@@ -210,8 +210,9 @@ def run(smoke: bool | None = None):
         [sys.executable, "-c", SCRIPT, json.dumps(params)], env=env,
         capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
-        emit("gossip/error", 1, proc.stderr[-300:].replace(",", ";"))
-        return None
+        raise RuntimeError(
+            f"gossip: child exited {proc.returncode}: {proc.stderr[-2000:]}"
+        )
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     base = out["exact"]["wire_bytes_to_target"]
     for mode, r in out.items():

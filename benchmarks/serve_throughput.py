@@ -26,15 +26,18 @@ from benchmarks.common import ROOT, emit, save_json
 
 
 def _serve_dict(extra_args, label: str):
-    """One serve_dict --json subprocess; returns its BENCH payload."""
+    """One serve_dict --json subprocess; returns its BENCH payload and
+    raises when the child failed (a run missing rows must not pass)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = str(ROOT / "src")
     cmd = [sys.executable, "-m", "repro.launch.serve_dict", "--json", *extra_args]
     proc = subprocess.run(cmd, env=env, capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
-        emit(f"serve/{label}/error", 1, proc.stderr[-300:].replace(",", ";"))
-        return None
+        raise RuntimeError(
+            f"serve/{label}: serve_dict exited {proc.returncode}: "
+            f"{proc.stderr[-2000:]}"
+        )
     bench_lines = [l for l in proc.stdout.splitlines() if l.startswith("BENCH ")]
     return json.loads(bench_lines[-1][len("BENCH "):])
 
@@ -52,15 +55,14 @@ def run(smoke: bool | None = None):
         "--grow-at", str(grow_at), "--grow-model", "2",
         "--mesh", "1x2", "--micro-batch", "16",
     ], "single")
-    if out is not None:
-        results["single"] = out
-        emit("serve/samples_per_s", f"{out['samples_per_s']:.1f}")
-        for p in ("p50", "p95", "p99"):
-            if p in out.get("latency_ms", {}):
-                emit(f"serve/latency_{p}_ms", f"{out['latency_ms'][p]:.1f}")
-        emit("serve/fit_steps", out["fit_steps"])
-        emit("serve/grow_events", len(out["grow_events"]),
-             "mid-stream model-axis growth" if out["grow_events"] else "")
+    results["single"] = out
+    emit("serve/samples_per_s", f"{out['samples_per_s']:.1f}")
+    for p in ("p50", "p95", "p99"):
+        if p in out.get("latency_ms", {}):
+            emit(f"serve/latency_{p}_ms", f"{out['latency_ms'][p]:.1f}")
+    emit("serve/fit_steps", out["fit_steps"])
+    emit("serve/grow_events", len(out["grow_events"]),
+         "mid-stream model-axis growth" if out["grow_events"] else "")
 
     # -- serving-plane scaling: router with 1 and 2 replicas --------------
     # Same stream and per-replica mesh; one rolling publish mid-stream so
@@ -73,8 +75,6 @@ def run(smoke: bool | None = None):
             "--replicas", str(n), "--router",
             "--publish-at", str(samples // 2),
         ], f"r{n}")
-        if out is None:
-            continue
         results[f"replicas={n}"] = out
         emit(f"serve/r{n}/agg_samples_per_s", f"{out['agg_samples_per_s']:.1f}")
         if out.get("p99_ms") is not None:

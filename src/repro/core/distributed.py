@@ -254,10 +254,8 @@ class DistConfig:
       data_axes        mesh axes the sample batch is sharded over.
       pod_axis         mesh axis name of the inter-pod hop (hier modes).
       use_kernel       fuse the local hot loop with the Pallas
-                       dict_dual_step kernel.
-      kernel_interpret Pallas interpret mode: None -> auto-detect (interpret
-                       only where there is no Mosaic lowering, i.e. CPU);
-                       True/False force it explicitly.
+                       dict_dual_step kernel (interpret mode follows the
+                       backend: `repro.kernels.interpret_mode`).
     """
 
     mode: str = "exact_fista"  # see MODES
@@ -286,9 +284,6 @@ class DistConfig:
     data_axes: Tuple[str, ...] = (dist.DATA_AXIS,)
     pod_axis: str = dist.POD_AXIS  # inter-pod gossip axis (hier modes)
     use_kernel: bool = False  # fuse local hot loop with the Pallas kernel
-    # Pallas interpret mode: None -> auto-detect (interpret only where there
-    # is no Mosaic lowering, i.e. CPU); True/False force it explicitly.
-    kernel_interpret: Optional[bool] = None
 
     def __post_init__(self):
         """Construction-time validation of cross-field requirements.
@@ -417,13 +412,24 @@ _quantize_q8 = dist.quantize_q8
 _dequantize_q8 = dist.dequantize_q8
 
 
-def resolve_kernel_interpret(flag: Optional[bool]) -> bool:
-    """Resolve DistConfig.kernel_interpret: an explicit bool wins; None means
-    auto — Pallas interpret mode only on CPU backends (no Mosaic/Triton
-    lowering there), compiled kernels everywhere else."""
-    if flag is None:
-        return jax.default_backend() == "cpu"
-    return bool(flag)
+# The engine's matmul precision, decided once: every program body is traced
+# with f32 matmuls at HIGHEST.  A TPU computes an f32 matmul at DEFAULT
+# precision as one bf16 pass (~3 significant digits), which would make the
+# engine disagree with its f32 reference far beyond reduction-order noise
+# and bias the step-size estimate; HIGHEST keeps the paper's f32 semantics.
+# Lower-precision storage and matmuls are a separate, measured change.
+MATMUL_PRECISION = "highest"
+
+
+def _f32_matmuls(body):
+    """`body` traced with the engine's matmul precision."""
+
+    @functools.wraps(body)
+    def traced(*args):
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return body(*args)
+
+    return traced
 
 
 # ---------------------------------------------------------------------------
@@ -449,7 +455,6 @@ def _local_code_and_back(
             gamma=reg.gamma,
             delta=reg.delta,
             nonneg=reg.nonneg,
-            interpret=resolve_kernel_interpret(cfg.kernel_interpret),
         )
     y = reg.ystar(nu @ W_loc)  # (B, K_loc)
     return y, y @ W_loc.T
@@ -714,10 +719,10 @@ class DistributedSparseCoder:
         # t0 is traced, not static, so varying it never recompiles.
         t_spec = P()
         # nu/y leave the solve un-replicated along `model` (each agent its own
-        # estimate), hence check_rep=False on the shard_map.
+        # estimate), hence check_vma=False on the shard_map.
         self._solve = jax.jit(
             shard_map(
-                self._solve_body,
+                _f32_matmuls(self._solve_body),
                 mesh=mesh,
                 in_specs=(self._w_spec, self._x_spec, t_spec),
                 out_specs=(P(da, None), P(da, agent_spec)),
@@ -726,7 +731,7 @@ class DistributedSparseCoder:
         )
         self._fit = jax.jit(
             shard_map(
-                self._fit_body,
+                _f32_matmuls(self._fit_body),
                 mesh=mesh,
                 in_specs=(self._w_spec, self._x_spec, P(), t_spec),
                 out_specs=self._w_spec,
@@ -735,7 +740,7 @@ class DistributedSparseCoder:
         )
         self._score = jax.jit(
             shard_map(
-                self._score_body,
+                _f32_matmuls(self._score_body),
                 mesh=mesh,
                 in_specs=(self._w_spec, self._x_spec, t_spec),
                 out_specs=P(da),
@@ -746,18 +751,22 @@ class DistributedSparseCoder:
         # the reference engine's layout) and the per-rank adaptive step size.
         self._solve_stacked = jax.jit(
             shard_map(
-                lambda W_loc, x_loc, t0: tuple(
+                _f32_matmuls(lambda W_loc, x_loc, t0: tuple(
                     v[None] for v in self._solve_body(W_loc, x_loc, t0)
-                ),
+                )),
                 mesh=mesh,
                 in_specs=(self._w_spec, self._x_spec, t_spec),
                 out_specs=(P(agent_spec, *da, None), P(agent_spec, *da, None)),
                 check_vma=False,
             )
         )
+        self._init_w = jax.jit(
+            init_dictionary, static_argnames=("m", "k", "nonneg"),
+            out_shardings=NamedSharding(mesh, self._w_spec),
+        )
         self._mu = jax.jit(
             shard_map(
-                self._mu_body,
+                _f32_matmuls(self._mu_body),
                 mesh=mesh,
                 in_specs=(self._w_spec,),
                 out_specs=P(agent_spec),
@@ -1445,6 +1454,13 @@ class DistributedSparseCoder:
         snapshot while the learner advances the live copy; publishing is an
         atomic swap of the reference (see repro.runtime.service)."""
         return jax.device_put(W, NamedSharding(self.mesh, self._w_spec))
+
+    def init_dictionary(self, key: jax.Array, m: int, k: int) -> Array:
+        """A random unit-norm (M, K) dictionary (`core.dictionary.
+        init_dictionary`) created directly in the engine's W sharding: each
+        device draws only its own atom shard, so no device ever holds the
+        whole W or its temporaries."""
+        return self._init_w(key, m=m, k=k, nonneg=self.reg.nonneg)
 
     def grown(
         self, W: Array, extra_model: int, key: jax.Array, devices=None

@@ -86,6 +86,25 @@ def patch_dataset(
 # ---------------------------------------------------------------------------
 
 
+def planted_atoms(
+    cols: np.ndarray, m: int, nonneg: bool = False, seed: int = 0
+) -> np.ndarray:
+    """(m, len(cols)) float32 unit-norm columns of the planted dictionary.
+
+    Atom j is drawn from its own stream, seeded by (seed, j), so any subset
+    of a K-atom dictionary is generated without materializing the rest: a
+    stream of n samples touches at most n * sparsity atoms, whatever K is."""
+    out = np.empty((m, len(cols)), np.float32)
+    for c, j in enumerate(cols):
+        out[:, c] = np.random.default_rng((seed, int(j))).standard_normal(
+            m, dtype=np.float32
+        )
+    if nonneg:
+        np.abs(out, out=out)
+    out /= np.linalg.norm(out, axis=0, keepdims=True)
+    return out
+
+
 def sparse_stream(
     n: int,
     m: int = 32,
@@ -96,29 +115,32 @@ def sparse_stream(
     seed: int = 0,
     return_dictionary: bool = False,
 ):
-    """(n, m) stream of samples x = W0 y + noise with y `sparsity`-sparse.
+    """(n, m) float32 stream of samples x = W0 y + noise, y `sparsity`-sparse.
 
     The canonical planted sparse-code model used by the quickstarts, the
     learner tests, and the streaming-service/serve-throughput workloads
-    (deterministic, cheap, single-pass).  With `return_dictionary=True`
-    also returns the planted W0 (m, k_true) for recovery checks."""
+    (deterministic, cheap, single-pass).  Only the atoms the samples use
+    are generated (`planted_atoms`), so host memory and time scale with n,
+    not with k_true.  With `return_dictionary=True` also returns the whole
+    planted W0 (m, k_true) for recovery checks."""
     rng = np.random.default_rng(seed)
-    W0 = rng.normal(size=(m, k_true)).astype(np.float32)
-    if nonneg:
-        W0 = np.abs(W0)
-    W0 /= np.linalg.norm(W0, axis=0, keepdims=True)
-    Y = np.zeros((n, k_true), np.float32)
+    # drawn sample by sample, so the first n' samples of a longer stream
+    # are the n'-sample stream
+    idx = np.empty((n, sparsity), np.int64)
+    coef = np.empty((n, sparsity), np.float32)
+    X = np.empty((n, m), np.float32)
     for i in range(n):
-        idx = rng.choice(k_true, sparsity, replace=False)
+        idx[i] = rng.choice(k_true, sparsity, replace=False)
         sign = 1.0 if nonneg else rng.choice([-1.0, 1.0], sparsity)
-        Y[i, idx] = rng.uniform(0.5, 1.5, sparsity) * sign
-    X = (Y @ W0.T + noise * rng.standard_normal((n, m)).astype(np.float32)).astype(
-        np.float32
-    )
+        coef[i] = rng.uniform(0.5, 1.5, sparsity) * sign
+        X[i] = noise * rng.standard_normal(m, dtype=np.float32)
+    used, pos = np.unique(idx, return_inverse=True)
+    atoms = planted_atoms(used, m, nonneg, seed)  # (m, n_used)
+    X += np.einsum("nsm,ns->nm", atoms.T[pos.reshape(n, sparsity)], coef)
     if nonneg:
-        X = np.abs(X)
+        np.abs(X, out=X)
     if return_dictionary:
-        return X, W0
+        return X, planted_atoms(np.arange(k_true), m, nonneg, seed)
     return X
 
 

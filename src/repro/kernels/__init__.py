@@ -10,5 +10,14 @@ slstm_step/      — persistent-weights sLSTM sequence kernel (recurrent
 
 Each kernel package ships `kernel.py` (pl.pallas_call + BlockSpec),
 `ops.py` (jit'd padded wrapper), and `ref.py` (pure-jnp oracle used by the
-shape/dtype sweep tests).
+shape/dtype sweep tests).  Every op's `interpret=None` default resolves
+through `interpret_mode()`, the one place that decides it.
 """
+
+import jax
+
+
+def interpret_mode() -> bool:
+    """Pallas interpret mode, decided from the backend: the interpreter
+    only where Mosaic has no lowering (CPU); compiled kernels elsewhere."""
+    return jax.default_backend() == "cpu"

@@ -13,10 +13,11 @@ Tiling (DESIGN.md §5):
   Y  block (bb, bk) @ (i, j)    — written per step
   G  block (bb, M)  @ (i, 0)    — accumulated across j (init at j == 0)
 
-MXU alignment: bb, bk multiples of 8/128 are enforced by ops.py padding;
-M is padded to a multiple of 128 there as well.  The float32 accumulation
-for G lives in the output block (revisited across the j sweep, which Pallas
-keeps in VMEM because the index map is constant in j).
+Every block spans the whole of M, so at activation widths (M in the
+thousands) the tiles are chosen from a VMEM budget (ops.py `_tiles`) and
+the kernel is given an explicit scoped-VMEM limit to match.  Both dots run
+at HIGHEST precision: the kernel computes what the jnp path computes (f32
+products), not a single bf16 pass.
 """
 
 from __future__ import annotations
@@ -26,8 +27,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 Array = jax.Array
+
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(nu_ref, w_ref, y_ref, g_ref, *, gamma: float, delta: float, nonneg: bool):
@@ -36,7 +40,8 @@ def _kernel(nu_ref, w_ref, y_ref, g_ref, *, gamma: float, delta: float, nonneg: 
     nu = nu_ref[...]  # (bb, M)
     w = w_ref[...]  # (M, bk)
 
-    s = jnp.dot(nu, w, preferred_element_type=jnp.float32)  # (bb, bk) on MXU
+    s = jnp.dot(nu, w, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)  # (bb, bk) on MXU
     if nonneg:
         y = jnp.maximum(s - gamma, 0.0)
     else:
@@ -45,7 +50,11 @@ def _kernel(nu_ref, w_ref, y_ref, g_ref, *, gamma: float, delta: float, nonneg: 
 
     y_ref[...] = y.astype(y_ref.dtype)
 
-    g_contrib = jnp.dot(y, w.T.astype(jnp.float32), preferred_element_type=jnp.float32)
+    # y (bb, bk) contracted with w (M, bk) over bk: Y W^T without a transpose
+    g_contrib = jax.lax.dot_general(
+        y, w.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32,
+    )
 
     @pl.when(j == 0)
     def _init():
@@ -63,9 +72,10 @@ def dict_dual_step_pallas(
     gamma: float,
     delta: float,
     nonneg: bool,
-    block_b: int = 128,
-    block_k: int = 512,
-    interpret: bool = False,
+    block_b: int,
+    block_k: int,
+    vmem_limit_bytes: int,
+    interpret: bool,
 ) -> tuple[Array, Array]:
     """Raw pallas_call; shapes must already be tile-aligned (see ops.py)."""
     m, k = W.shape
@@ -91,6 +101,12 @@ def dict_dual_step_pallas(
             jax.ShapeDtypeStruct((b, k), nu.dtype),
             jax.ShapeDtypeStruct((b, m), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            # j accumulates into the G block, so only i may be split
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
+        ),
+        name="dict_dual_step",
         interpret=interpret,
     )(nu, W)
     return y, g.astype(nu.dtype)

@@ -1,16 +1,18 @@
 """jit'd public wrapper for flash attention.
 
 Handles GQA head layout, padding of S/T to tile multiples (with causal-safe
-key masking via an explicit length), and the interpret-mode fallback.
+key masking via an explicit length), and the backend's interpret mode.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 Array = jax.Array
@@ -28,8 +30,10 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
+    if interpret is None:
+        interpret = interpret_mode()
     b, hq, s, d = q.shape
     hkv, t = k.shape[1], k.shape[2]
     if hq % hkv:

@@ -8,10 +8,12 @@ CPU tests keep the XLA scan + interpret-mode validation)."""
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.slstm_step.kernel import slstm_seq_pallas
 
 Array = jax.Array
@@ -25,8 +27,10 @@ def slstm_block_kernel(
     x: Array,  # (B, S, D)
     *,
     n_heads: int,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
+    if interpret is None:
+        interpret = interpret_mode()
     b_sz, s, d = x.shape
     # hoisted input projections, stacked (4, S, B, D)
     x_proj = jnp.stack(

@@ -16,7 +16,7 @@ import re
 from repro.configs import get_config
 from repro.configs.base import SHAPES
 from repro.launch.dryrun import _DT_BYTES, _SHAPE_RE
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import TARGET_KIND, make_production_mesh, peaks
 from repro.optim import optimizers as opt_mod
 from repro.runtime import steps as S
 
@@ -94,9 +94,9 @@ def main():
     print(f"== {args.arch} x {args.shape} (trip-count weighted) ==")
     print(f"per-device flops {costs.flops:.3e}  bytes {costs.bytes:.3e}  "
           f"coll {costs.coll_bytes:.3e}")
-    print(f"t_compute {costs.flops / HW['peak_flops_bf16']:.3e}s  "
-          f"t_memory {costs.bytes / HW['hbm_bw']:.3e}s  "
-          f"t_coll {costs.coll_bytes / HW['ici_bw']:.3e}s")
+    print(f"t_compute {costs.flops / peaks(TARGET_KIND)['peak_flops_bf16']:.3e}s  "
+          f"t_memory {costs.bytes / peaks(TARGET_KIND)['hbm_bw']:.3e}s  "
+          f"t_coll {costs.coll_bytes / peaks(TARGET_KIND)['ici_bw']:.3e}s")
     for k in costs.coll:
         if costs.coll_counts[k]:
             print(f"  {k:20s} n={costs.coll_counts[k]:6.0f}  {costs.coll[k]:16,.0f} B")

@@ -28,9 +28,8 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import SHAPES, ShapeConfig, cell_supported
-from repro.launch.mesh import HW, make_production_mesh
+from repro.launch.mesh import TARGET_KIND, make_production_mesh, peaks
 from repro.optim import optimizers as opt_mod
-from repro.runtime import compat
 from repro.runtime import dist
 from repro.runtime import steps as S
 
@@ -122,9 +121,9 @@ def analyze(lowered, n_chips: int, extra: dict) -> dict:
     bytes_acc = costs.bytes
     coll_bytes = costs.coll_bytes
 
-    t_compute = flops / HW["peak_flops_bf16"]
-    t_memory = bytes_acc / HW["hbm_bw"]
-    t_coll = coll_bytes / HW["ici_bw"]
+    t_compute = flops / peaks(TARGET_KIND)["peak_flops_bf16"]
+    t_memory = bytes_acc / peaks(TARGET_KIND)["hbm_bw"]
+    t_coll = coll_bytes / peaks(TARGET_KIND)["ici_bw"]
     dominant = max(
         (("compute", t_compute), ("memory", t_memory), ("collective", t_coll)),
         key=lambda kv: kv[1],
@@ -139,7 +138,7 @@ def analyze(lowered, n_chips: int, extra: dict) -> dict:
             "hlo_flops": flops,
             "hlo_bytes_accessed": bytes_acc,
             "collective_bytes": coll_bytes,
-            "peak_memory_bytes": compat.peak_memory_bytes(ma),
+            "peak_memory_bytes": int(ma.peak_memory_in_bytes),
             "argument_bytes": int(ma.argument_size_in_bytes),
             "output_bytes": int(ma.output_size_in_bytes),
         },

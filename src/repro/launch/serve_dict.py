@@ -49,9 +49,21 @@ and disables the grow/drain drills:
   PYTHONPATH=src python -m repro.launch.serve_dict \\
       --replicas 2 --mesh 1x2 --samples 400 --publish-at 200 --grow-at 0
 
+On a chip, the same entry point runs a real-width deployment; `--platform
+tpu` makes it refuse to run anywhere else.  A sparse dictionary over
+Llama-3-70B-width residual activations, K = 8x M, on one chip:
+
+  PYTHONPATH=src python -m repro.launch.serve_dict --platform tpu \\
+      --m 8192 --atoms-per-agent 65536 --mesh 1x1 --iters 100 \\
+      --gamma 0.05 --delta 0.2 --micro-batch 256 --max-wait-ms 1000 \\
+      --samples 2048 --grow-at 0
+
 Prints throughput (samples/s), per-sample latency percentiles, learner
 progress, and the growth event; `--json` additionally emits one
-machine-readable line (consumed by benchmarks/serve_throughput.py).
+machine-readable line (consumed by benchmarks/serve_throughput.py).  With
+learning on, a run whose learner failed a step or took none exits
+non-zero.  JAX's compile cache is `<repo>/.jax_cache` unless
+JAX_COMPILATION_CACHE_DIR names another.
 """
 
 from __future__ import annotations
@@ -65,15 +77,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.conjugates import make_task
-from repro.core.dictionary import init_dictionary
 from repro.core.distributed import DistConfig, DistributedSparseCoder
 from repro.data.synthetic import sparse_stream
+from repro.launch.mesh import require_platform, use_repo_compile_cache
 from repro.runtime import dist
 from repro.runtime.service import DictionaryService, ServiceConfig
 from repro.runtime.serving import ReplicaSet, Router, RouterConfig, device_pools
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", type=str, default="sparse_svd")
     ap.add_argument("--gamma", type=float, default=0.25)
@@ -160,10 +172,27 @@ def main() -> None:
                          "publish (a perturbed dictionary fans out to the "
                          "replicas one at a time; 0 = never)")
     ap.add_argument("--no-learn", action="store_true")
+    ap.add_argument("--use-kernel", action="store_true",
+                    help="run the engine's hot loop through the fused Pallas "
+                         "dict_dual_step kernel")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--json", action="store_true",
                     help="emit a single BENCH json line at the end")
-    args = ap.parse_args()
+    ap.add_argument("--platform", type=str, default="", choices=["", "cpu", "tpu"],
+                    help="refuse to run unless JAX's devices are on this "
+                         "platform ('' = whatever JAX finds)")
+    return ap.parse_args(argv)
+
+
+def run(argv=None) -> dict:
+    """One serving run from command-line arguments; prints its report and
+    returns a record of it: the service or fleet stats, and for a single
+    service also the served (nu, y) per sample, the stream X, the initial
+    dictionary W0 and the coder (for reference checks)."""
+    args = parse_args(argv)
+    if args.platform:
+        require_platform(args.platform)
+    use_repo_compile_cache()
 
     dims = [int(v) for v in args.mesh.split("x")]
     # How many AGENT levels the mesh must carry (model + outer levels):
@@ -253,7 +282,6 @@ def main() -> None:
     # one atom block per AGENT: the hierarchical family shards atoms over
     # (all outer levels) x model.
     k0 = args.atoms_per_agent * m_axis * outer
-    W0 = init_dictionary(jax.random.PRNGKey(args.seed), args.m, k0, nonneg=reg.nonneg)
     dist_cfg = DistConfig(
         mode=args.mode, iters=args.iters, topology=args.topology,
         topology_p=args.topology_p, topology_seed=args.topology_seed,
@@ -264,6 +292,7 @@ def main() -> None:
         pod_topology=args.pod_topology,
         pod_gossip_every=args.pod_gossip_every,
         levels=args.levels,
+        use_kernel=args.use_kernel,
     )
     svc_cfg = ServiceConfig(
         micro_batch=args.micro_batch,
@@ -274,10 +303,10 @@ def main() -> None:
     X = sparse_stream(args.samples, m=args.m, k_true=k0, nonneg=reg.nonneg,
                       seed=args.seed + 1)
     if fleet_mode:
-        _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh, per_replica,
-                   W0, X)
-        return
+        return _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh,
+                          per_replica, k0, X)
     coder = DistributedSparseCoder(build_mesh(), res, reg, dist_cfg)
+    W0 = coder.init_dictionary(jax.random.PRNGKey(args.seed), args.m, k0)
     comb = coder.combiner_info()
 
     print(f"serve_dict: task={args.task} mode={args.mode} mesh={args.mesh} "
@@ -333,12 +362,16 @@ def main() -> None:
     assert len(results) == args.samples, "dropped samples!"
 
     lat = stats.get("latency_ms", {})
-    print(f"coded {stats['coded']}/{args.samples} samples in {wall_s:.2f}s "
+    print("compile s: " + "  ".join(
+        f"{k} {v:.2f}" for k, v in stats["compile_s"].items()))
+    print(f"coded {stats['coded']}/{args.samples} samples in "
+          f"{stats['batches']} micro-batches, {wall_s:.2f}s "
           f"({stats['coded'] / wall_s:.1f} samples/s)")
     print(f"latency ms: p50 {lat.get('p50', float('nan')):.1f}  "
           f"p95 {lat.get('p95', float('nan')):.1f}  "
           f"p99 {lat.get('p99', float('nan')):.1f}")
-    print(f"fit_steps {stats['fit_steps']}  published {stats['published']}  "
+    print(f"fit_steps {stats['fit_steps']}  fit_failures {stats['fit_failures']}  "
+          f"published {stats['published']}  "
           f"grow_events {len(stats['grow_events'])}  "
           f"drain_events {len(stats['drain_events'])}  y dims seen {k_dims}")
     print(f"mean ||nu||: first batch {pre:.4f} -> last batch {post:.4f}")
@@ -371,15 +404,19 @@ def main() -> None:
             "residual_last": float(post),
         }
         print("BENCH " + json.dumps(payload))
+    return {"mode": "single", "args": args, "stats": stats, "wall_s": wall_s,
+            "results": results, "X": X, "W0": W0, "coder": coder,
+            "res": res, "reg": reg}
 
 
 def _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh, per_replica,
-               W0, X) -> None:
+               k0, X) -> dict:
     """Fleet-mode serving loop: N replicas on disjoint device pools behind
     the freshness-aware Router, with one optional rolling publish."""
     pools = device_pools(args.replicas, per_replica)
     coders = [DistributedSparseCoder(build_mesh(p), res, reg, dist_cfg)
               for p in pools]
+    W0 = coders[0].init_dictionary(jax.random.PRNGKey(args.seed), args.m, k0)
     comb = coders[0].combiner_info()
     print(f"serve_dict[fleet]: task={args.task} mode={args.mode} "
           f"replicas={args.replicas} mesh={args.mesh}/replica "
@@ -406,7 +443,7 @@ def _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh, per_replica,
                     futures[-1].result(timeout=600)
                     rng = np.random.default_rng(args.seed + 3)
                     W1 = np.asarray(W0) + 0.01 * rng.standard_normal(
-                        W0.shape).astype(np.float32)
+                        W0.shape, dtype=np.float32)
                     if reg.nonneg:
                         W1 = np.maximum(W1, 0.0)
                     W1 /= np.maximum(
@@ -437,6 +474,8 @@ def _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh, per_replica,
     print(f"latency ms: p50 {lat.get('p50', float('nan')):.1f}  "
           f"p95 {lat.get('p95', float('nan')):.1f}  "
           f"p99 {lat.get('p99', float('nan')):.1f}")
+    print("coded per replica: " + "  ".join(
+        f"{name} {r['coded']}" for name, r in per_rep.items()))
     print(f"routed {rstats['routed']}  rerouted {rstats['rerouted']}  "
           f"failed {rstats['failed']}  publishes {fstats['publishes']} "
           f"{published}")
@@ -460,6 +499,27 @@ def _run_fleet(args, res, reg, dist_cfg, svc_cfg, build_mesh, per_replica,
             "per_replica": per_rep,
         }
         print("BENCH " + json.dumps(payload))
+    return {"mode": "fleet", "args": args, "wall_s": wall_s,
+            "router": rstats, "fleet": fstats, "per_replica": per_rep}
+
+
+def learner_failure(stats: dict, learn: bool) -> str:
+    """Why a learning run must not count as a success ('' when it may): a
+    failed fit step, or learning on and no fit step taken."""
+    if stats["fit_failures"]:
+        return (f"{stats['fit_failures']} fit step(s) failed; first error: "
+                f"{stats['fit_first_error']}")
+    if learn and stats["fit_steps"] == 0:
+        return "learning was on but the learner took no fit step"
+    return ""
+
+
+def main(argv=None) -> None:
+    out = run(argv)
+    if out["mode"] == "single":
+        why = learner_failure(out["stats"], not out["args"].no_learn)
+        if why:
+            raise SystemExit(f"serve_dict: {why}")
 
 
 if __name__ == "__main__":

@@ -158,7 +158,7 @@ def attention(
     rope_base: float = 10000.0,
     impl: str = "blockwise",
     block: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Array:
     """Self-attention over the full sequence (training / prefill)."""
     q, k, v = _project_qkv(

@@ -377,13 +377,9 @@ def slstm_block_auto(params: dict, x: Array, *, n_heads: int,
     b = x.shape[0]
     while dp_axes and b % _prod(sizes, dp_axes):
         dp_axes = dp_axes[1:]
-    # Going manual over the DP axes only (model stays auto/GSPMD for the
-    # TP-sharded W matrices) needs partial-manual shard_map; on jax
-    # versions without it the plain GSPMD path is the only correct option
-    # (same math, it just pays the per-timestep gradient all-reduce).
-    if not dp_axes or not (
-        dist.supports_partial_manual() or set(dp_axes) == set(sizes)
-    ):
+    # Manual over the DP axes only (model stays auto/GSPMD for the
+    # TP-sharded W matrices); with no DP axis the plain GSPMD path runs.
+    if not dp_axes:
         return slstm_block(params, x, n_heads=n_heads, return_cache=return_cache)
     bspec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     xspec = P(bspec, None, None)

@@ -1,70 +1,22 @@
-"""JAX version-compatibility shims for mesh construction and `shard_map`.
+"""Mesh construction and `shard_map` for jax 0.9.0, the one version supported.
 
-The public JAX surface for manual-collectives programming moved twice:
+This module is the ONLY place in the repo that calls JAX's mesh and
+shard_map constructors.  Everything else goes through
+`repro.runtime.dist`, which re-exports the entry points defined here.
 
-  jax <= 0.5   `jax.experimental.shard_map.shard_map(f, mesh, in_specs,
-               out_specs, check_rep=..., auto=frozenset())`;
-               `AbstractMesh(((name, size), ...))` takes name/size pairs.
-  jax >= 0.6   `jax.shard_map(f, mesh=..., in_specs=..., out_specs=...,
-               check_vma=..., axis_names=frozenset())`;
-               `AbstractMesh(axis_sizes, axis_names)` takes two tuples.
-
-This module is the ONLY place in the repo allowed to know about that drift.
-Everything else goes through `repro.runtime.dist`, which re-exports the
-unified entry points defined here.  The wrappers accept BOTH spellings of
-each kwarg pair and translate to whatever the installed jax understands:
-
-  check_vma (new)  <->  check_rep (old)    replication/varying-manual-axes
-                                           check on shard_map outputs
-  axis_names (new) <->  auto (old)         manual axes vs. their complement
-
-Supported and CI-pinned: jax 0.4.3x.  The new-surface branches keep the
-same code importable on jax >= 0.6 without edits.
+Every mesh the repo builds has `Auto` axis types, whether it spans all
+devices or a caller-given subset (a fleet replica's pool): `jax.make_mesh`
+alone would give `Explicit` axes, so the single service and the fleet
+would run on different kinds of mesh.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Callable, Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # AbstractMesh exists from jax 0.4.31 on (either signature)
-    from jax.sharding import AbstractMesh as _AbstractMesh
-except ImportError:  # pragma: no cover — very old jax
-    _AbstractMesh = None
-
-JAX_VERSION: Tuple[int, ...] = tuple(
-    int(p) for p in jax.__version__.split(".")[:3] if p.isdigit()
-)
-
-
-def resolve_shard_map() -> Callable:
-    """The installed raw shard_map, wherever this jax version keeps it."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map as fn  # jax <= 0.5
-
-    return fn
-
-
-_RAW_SHARD_MAP = resolve_shard_map()
-_RAW_PARAMS = inspect.signature(_RAW_SHARD_MAP).parameters
-# One probe decides the whole dialect: the kwarg rename (check_rep ->
-# check_vma) and the manual-axes rename (auto -> axis_names) shipped together.
-_NEW_SURFACE = "check_vma" in _RAW_PARAMS
-
-# Partial-manual shard_map (manual over a strict subset of the mesh axes,
-# GSPMD handling the rest) only became usable with the new surface: the
-# 0.4.x `auto=` mode has no autodiff rules (`if auto: raise
-# NotImplementedError` in its transpose) and trips an XLA
-# IsManualSubgroup() check on CPU even in the forward pass.  Callers of
-# version-gated optimizations (e.g. the manual-over-DP sLSTM block) must
-# consult this and keep a full-GSPMD fallback.
-SUPPORTS_PARTIAL_MANUAL = _NEW_SURFACE
+from jax.sharding import AbstractMesh, AxisType, Mesh
 
 
 def shard_map(
@@ -73,50 +25,15 @@ def shard_map(
     in_specs,
     out_specs,
     *,
-    check_vma: Optional[bool] = None,
-    check_rep: Optional[bool] = None,
+    check_vma: bool = True,
     axis_names: Optional[frozenset] = None,
-    auto: Optional[frozenset] = None,
 ):
-    """Version-portable shard_map.
-
-    `check_vma`/`check_rep` name the same output-replication check; pass
-    either.  `axis_names` (the axes the body is MANUAL over) and `auto`
-    (the axes left to GSPMD) are complements over `mesh.axis_names`; pass
-    at most one.  Defaults: check on, manual over every mesh axis.
-    """
-    if check_vma is not None and check_rep is not None and check_vma != check_rep:
-        raise TypeError("pass only one of check_vma / check_rep")
-    check = True
-    if check_vma is not None:
-        check = check_vma
-    if check_rep is not None:
-        check = check_rep
-
-    if axis_names is not None and auto is not None:
-        raise TypeError("pass only one of axis_names / auto")
-    all_axes = frozenset(mesh.axis_names)
-    if axis_names is not None:
-        manual = frozenset(axis_names)
-    elif auto is not None:
-        manual = all_axes - frozenset(auto)
-    else:
-        manual = all_axes
-
-    kwargs = {}
-    if _NEW_SURFACE:
-        kwargs["check_vma"] = check
-        if manual != all_axes:
-            kwargs["axis_names"] = manual
-    else:
-        if manual != all_axes:
-            raise NotImplementedError(
-                "partial-manual shard_map (axis_names ⊂ mesh axes) is broken "
-                "on this jax version — gate the call on "
-                "compat.SUPPORTS_PARTIAL_MANUAL and fall back to GSPMD"
-            )
-        kwargs["check_rep"] = check
-    return _RAW_SHARD_MAP(
+    """`jax.shard_map`, manual over `axis_names` (default: every mesh
+    axis), with the varying-manual-axes check `check_vma`."""
+    kwargs = {"check_vma": check_vma}
+    if axis_names is not None and frozenset(axis_names) != frozenset(mesh.axis_names):
+        kwargs["axis_names"] = frozenset(axis_names)
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
     )
 
@@ -127,56 +44,29 @@ def make_mesh(
     *,
     devices: Optional[Sequence] = None,
 ) -> Mesh:
-    """Mesh from an int shape tuple, on `devices` (default: all of them).
-
-    Prefers `jax.make_mesh` (jax >= 0.4.35, picks a contiguous device
-    order); falls back to reshaping the raw device list.
-    """
+    """Auto-typed mesh from an int shape tuple, on `devices` (default: all
+    of them, in the contiguous order `jax.make_mesh` picks)."""
     shape = tuple(int(s) for s in axis_shapes)
     names = tuple(axis_names)
     if len(shape) != len(names):
         raise ValueError(f"shape {shape} vs axis names {names}")
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, names)
-    devs = np.asarray(devices if devices is not None else jax.devices())
+    auto = (AxisType.Auto,) * len(shape)
+    if devices is None:
+        return jax.make_mesh(shape, names, axis_types=auto)
+    devs = np.asarray(devices)
     need = int(np.prod(shape))
     if devs.size < need:
         raise ValueError(f"mesh {names}={shape} needs {need} devices, have {devs.size}")
-    return Mesh(devs.reshape(-1)[:need].reshape(shape), names)
+    return Mesh(devs.reshape(-1)[:need].reshape(shape), names, axis_types=auto)
 
 
 def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """AbstractMesh (shape-only, no devices) across both constructor
-    signatures: (sizes, names) on jax >= 0.5, ((name, size), ...) before."""
-    if _AbstractMesh is None:  # pragma: no cover
-        raise ImportError("this jax version has no AbstractMesh")
+    """AbstractMesh (shape-only, no devices) from an int shape tuple."""
     shape = tuple(int(s) for s in axis_shapes)
     names = tuple(axis_names)
-    try:
-        return _AbstractMesh(shape, names)
-    except TypeError:
-        return _AbstractMesh(tuple(zip(names, shape)))
-
-
-def peak_memory_bytes(memory_stats) -> int:
-    """Per-device peak memory from a CompiledMemoryStats.  jax >= 0.5 exposes
-    `peak_memory_in_bytes`; on 0.4.x the closest portable figure is the sum
-    of live buffer classes (arguments + outputs + temporaries), an upper
-    bound that ignores donation overlap."""
-    peak = getattr(memory_stats, "peak_memory_in_bytes", 0)
-    if peak:
-        return int(peak)
-    # donated buffers (aliased inputs/outputs) would otherwise count twice
-    return int(
-        memory_stats.argument_size_in_bytes
-        + memory_stats.output_size_in_bytes
-        + memory_stats.temp_size_in_bytes
-        - getattr(memory_stats, "alias_size_in_bytes", 0)
-    )
+    return AbstractMesh(shape, names, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def axis_sizes(mesh) -> Dict[str, int]:
-    """Axis-name -> size for Mesh and AbstractMesh on every supported jax
-    (`.shape` is an OrderedDict on both, but spelled differently pre/post
-    the AbstractMesh rework)."""
+    """Axis-name -> size for Mesh and AbstractMesh."""
     return {str(k): int(v) for k, v in dict(mesh.shape).items()}

@@ -3,9 +3,10 @@
 The paper's protocol (arXiv:1402.1515) maps the network of N agents onto
 the `model` axis of a device mesh and realizes gossip as collectives over
 that axis.  Every mesh, every `shard_map` entry, and every gossip exchange
-in the repo is constructed HERE, so (a) jax API drift is absorbed once (in
-runtime/compat.py, which this module fronts), and (b) new topologies,
-combiners, or backends plug in at one seam instead of per solver.
+in the repo is constructed HERE, so (a) the jax mesh/shard_map API is
+called in one place (runtime/compat.py, which this module fronts), and (b)
+new topologies, combiners, or backends plug in at one seam instead of per
+solver.
 
 Mode -> collective mapping (core/distributed.py consumes these):
 
@@ -99,7 +100,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime import compat
 from repro.runtime.compat import (  # re-exported: THE way to get these
     abstract_mesh,
     axis_sizes,
@@ -109,7 +109,6 @@ from repro.runtime.compat import (  # re-exported: THE way to get these
 
 __all__ = [
     "shard_map",
-    "supports_partial_manual",
     "make_mesh",
     "abstract_mesh",
     "axis_sizes",
@@ -155,13 +154,6 @@ Array = jax.Array
 MODEL_AXIS = "model"
 DATA_AXIS = "data"
 POD_AXIS = "pod"
-
-
-def supports_partial_manual() -> bool:
-    """Whether shard_map can go manual over a strict SUBSET of mesh axes
-    (GSPMD keeping the rest).  False on jax 0.4.x/0.5.x — version-gated
-    optimizations (manual-over-DP sLSTM) must keep a full-GSPMD fallback."""
-    return compat.SUPPORTS_PARTIAL_MANUAL
 
 
 # ---------------------------------------------------------------------------

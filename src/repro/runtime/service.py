@@ -60,12 +60,11 @@ exactly that contract:
     restarting at A_0.  Same swap mechanics and caveats as growth
     (applied at a learner step boundary, warmup off the serving path
     under the exec lock, stats + a drain event with the new identity).
-    One caveat on
-    jax 0.4.x: the new coder's programs can only be compiled via their
-    first execution, which must hold the exec lock (collectives from two
-    programs must not interleave on shared devices) — so an elastic-growth
-    swap pauses coding for one compile+warmup window.  Steady-state coding
-    and learning never recompile (fixed micro-batch shape).
+    The new coder's programs are compiled by their first execution, which
+    must hold the exec lock (collectives from two programs must not
+    interleave on shared devices) — so an elastic-growth swap pauses coding
+    for one compile+warmup window.  Steady-state coding and learning never
+    recompile (fixed micro-batch shape).
 
 Consistency model: a sample's code reflects the newest snapshot published
 at the time its micro-batch is flushed — bounded staleness of at most
@@ -75,6 +74,7 @@ at the time its micro-batch is flushed — bounded staleness of at most
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import queue
 import threading
@@ -90,6 +90,35 @@ from repro.core.distributed import DistributedSparseCoder
 from repro.runtime import dist
 
 Array = jax.Array
+
+
+class _CompileClock:
+    """Seconds JAX spends tracing, lowering and compiling on the calling
+    thread, summed from jax.monitoring's compile-duration events (one
+    process-wide listener, installed on first use)."""
+
+    _local = threading.local()
+    _install_lock = threading.Lock()
+    _installed = False
+
+    @classmethod
+    def _listen(cls, event: str, duration: float, **_) -> None:
+        if event.startswith("/jax/core/compile/") and getattr(cls._local, "on", False):
+            cls._local.total += duration
+
+    @classmethod
+    @contextlib.contextmanager
+    def measure(cls, out: Dict[str, float], key: str):
+        with cls._install_lock:
+            if not cls._installed:
+                jax.monitoring.register_event_duration_secs_listener(cls._listen)
+                cls._installed = True
+        cls._local.on, cls._local.total = True, 0.0
+        try:
+            yield
+        finally:
+            cls._local.on = False
+            out[key] = cls._local.total
 
 
 @dataclasses.dataclass(frozen=True)
@@ -240,7 +269,8 @@ class DictionaryService:
     # `self._exec_lock` (multi-device programs with collectives must not
     # interleave).  Extending the service = extending these tuples.
     _GUARDED_BY_LOCK = (
-        "submitted", "coded", "fit_steps", "fit_failures", "learn_dropped",
+        "submitted", "coded", "batches", "fit_steps", "fit_failures",
+        "learn_dropped",
         "fit_first_error", "published", "grow_events", "drain_events",
         "_latencies",
         "_sched_t", "_coder", "_live", "_snap", "_comb_info",
@@ -298,6 +328,7 @@ class DictionaryService:
         # count ahead of its fit_steps).
         self.submitted = 0
         self.coded = 0
+        self.batches = 0  # coded micro-batches (engine solve calls)
         self.fit_steps = 0
         self.fit_failures = 0
         self.learn_dropped = 0
@@ -316,6 +347,9 @@ class DictionaryService:
         # flight when a snapshot lands still carries the old version).
         self._snap_version = 0
         self._serving_version = 0
+        # Seconds the start-up warmup spent tracing + compiling each engine
+        # program (set once by start(); growth/drain warmups not included).
+        self.compile_s: Dict[str, float] = {}
 
     # -- helpers ----------------------------------------------------------
 
@@ -382,18 +416,23 @@ class DictionaryService:
 
     # -- lifecycle --------------------------------------------------------
 
-    def _warmup(self, coder: DistributedSparseCoder, W: Array) -> None:
+    def _warmup(self, coder: DistributedSparseCoder, W: Array) -> Dict[str, float]:
         """Trigger the jit compiles on a zero micro-batch so the first real
         request (and the first post-growth request) pays no compile stall.
         Results are discarded; with mu_w=0 the fit warmup is a no-op step.
+        Returns the trace+compile seconds of each program.
 
         Runs WITHOUT taking `_exec_lock` itself: start() calls it before
         any worker thread exists, and _maybe_grow() calls it while already
         holding the lock (threading.Lock is not reentrant)."""
+        secs: Dict[str, float] = {}
         z = jnp.zeros((self._pad, self._m), jnp.float32)
-        jax.block_until_ready(coder.solve(W, z))  # analyze: allow(exec-lock)
+        with _CompileClock.measure(secs, "solve"):
+            jax.block_until_ready(coder.solve(W, z))  # analyze: allow(exec-lock)
         if self.cfg.learn:
-            jax.block_until_ready(coder.fit_batch(W, z, 0.0))  # analyze: allow(exec-lock)
+            with _CompileClock.measure(secs, "fit"):
+                jax.block_until_ready(coder.fit_batch(W, z, 0.0))  # analyze: allow(exec-lock)
+        return secs
 
     def start(self) -> "DictionaryService":
         if self._threads:
@@ -404,7 +443,7 @@ class DictionaryService:
                 "DictionaryService (counters and queues are single-run)"
             )
         if self.cfg.warmup:
-            self._warmup(self._coder, self._snap)
+            self.compile_s = self._warmup(self._coder, self._snap)
         self._t_start = time.perf_counter()
         self._threads = [
             threading.Thread(target=self._batcher_loop, name="dict-batcher", daemon=True),
@@ -583,7 +622,7 @@ class DictionaryService:
         # the swap below re-checks the coder so a concurrent grow/drain that
         # changed the mesh underneath us fails loudly instead of installing
         # a stale-sharded buffer.
-        W_dev = coder.snapshot(jnp.asarray(W, jnp.float32))
+        W_dev = coder.snapshot(W)
         with self._lock:
             if self._coder is not coder:
                 raise RuntimeError(
@@ -623,6 +662,8 @@ class DictionaryService:
             out = {
                 "submitted": self.submitted,
                 "coded": self.coded,
+                "batches": self.batches,
+                "compile_s": dict(self.compile_s),
                 "fit_steps": self.fit_steps,
                 "fit_failures": self.fit_failures,
                 "fit_first_error": self.fit_first_error,
@@ -719,6 +760,7 @@ class DictionaryService:
                 for it in items:
                     self._latencies.append(t_done - it.t_submit)
                 self.coded += len(items)
+                self.batches += 1
                 self._serving_version = ver
                 if dropped:
                     self.learn_dropped += 1

@@ -26,22 +26,31 @@ def _run(code: str, n_devices: int = 4, timeout: int = 900):
 
 
 def test_kernel_interpret_auto_detects_backend():
-    """Default (None) resolves per backend: interpret only on CPU, compiled
-    elsewhere; explicit booleans always win."""
+    """Interpret mode is decided in one place, from the backend: the
+    interpreter only on CPU, compiled kernels elsewhere; the engine exposes
+    no option for it and the kernel op defaults to that decision."""
+    import inspect
+
     import jax
 
-    from repro.core.distributed import DistConfig, resolve_kernel_interpret
+    from repro.core.distributed import DistConfig
+    from repro.kernels import interpret_mode
+    from repro.kernels.dict_dual_step.ops import dict_dual_step
 
-    assert DistConfig().kernel_interpret is None
-    assert resolve_kernel_interpret(None) is (jax.default_backend() == "cpu")
-    assert resolve_kernel_interpret(True) is True
-    assert resolve_kernel_interpret(False) is False
+    assert interpret_mode() is (jax.default_backend() == "cpu")
+    assert not hasattr(DistConfig(), "kernel_interpret")
+    sig = inspect.signature(dict_dual_step)
+    assert sig.parameters["interpret"].default is None
 
 
 @pytest.mark.slow
 def test_ring_parity_and_identical_mu():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        # The engine's pmax'd power iteration and the reference's vmapped one
+        # sum in different orders (different fusions), so the two f32 step
+        # sizes agree to a few ulps, not bit for bit.
+        MU_RTOL = 4 * np.finfo(np.float32).eps
         from repro.core.conjugates import make_task
         from repro.core.distributed import DistributedSparseCoder, DistConfig, make_debug_mesh
         from repro.core.dictionary import blocks_from_full
@@ -72,7 +81,7 @@ def test_ring_parity_and_identical_mu():
         assert mus.shape == (N,)
         assert float(np.ptp(mus)) == 0.0, mus
         mu_ref = float(safe_diffusion_mu(res, reg, W_blocks))
-        assert abs(float(mus[0]) - mu_ref) < 1e-7 * mu_ref, (mus[0], mu_ref)
+        assert abs(float(mus[0]) - mu_ref) < MU_RTOL * mu_ref, (mus[0], mu_ref)
 
         # 2) per-agent (nu, y) parity with the reference diffusion engine.
         nu_ref, y_ref, _ = diffusion_infer(
@@ -105,6 +114,10 @@ def test_graph_mode_parity_with_reference_engine():
     combine."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        # The engine's pmax'd power iteration and the reference's vmapped one
+        # sum in different orders (different fusions), so the two f32 step
+        # sizes agree to a few ulps, not bit for bit.
+        MU_RTOL = 4 * np.finfo(np.float32).eps
         from repro.core.conjugates import make_task
         from repro.core.distributed import DistributedSparseCoder, DistConfig, make_debug_mesh
         from repro.core.dictionary import blocks_from_full
@@ -132,7 +145,7 @@ def test_graph_mode_parity_with_reference_engine():
             # graph mode uses the same pmax'd safe step as the ring family.
             mus = np.asarray(coder.adaptive_mu(Ws))
             assert float(np.ptp(mus)) == 0.0, (topology, mus)
-            assert abs(float(mus[0]) - mu_ref) < 1e-7 * mu_ref
+            assert abs(float(mus[0]) - mu_ref) < MU_RTOL * mu_ref
 
             nu_ref, y_ref, _ = diffusion_infer(
                 res, reg, W_blocks, x, jnp.asarray(A, jnp.float32),
@@ -160,6 +173,10 @@ def test_graph_tv_parity_with_reference_engine():
     same topology_seed run the identical combiner sequence."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        # The engine's pmax'd power iteration and the reference's vmapped one
+        # sum in different orders (different fusions), so the two f32 step
+        # sizes agree to a few ulps, not bit for bit.
+        MU_RTOL = 4 * np.finfo(np.float32).eps
         from repro.core.conjugates import make_task
         from repro.core.distributed import DistributedSparseCoder, DistConfig, make_debug_mesh
         from repro.core.dictionary import blocks_from_full
@@ -199,7 +216,7 @@ def test_graph_tv_parity_with_reference_engine():
             # static ring/graph families.
             mus = np.asarray(coder.adaptive_mu(Ws))
             assert float(np.ptp(mus)) == 0.0, (spec, mus)
-            assert abs(float(mus[0]) - mu_ref) < 1e-7 * mu_ref
+            assert abs(float(mus[0]) - mu_ref) < MU_RTOL * mu_ref
 
             # parity under the IDENTICAL time-varying callable A_t.
             nu_ref, y_ref, _ = diffusion_infer(
@@ -273,6 +290,10 @@ def test_hier_parity_with_reference_engine():
     """
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        # The engine's pmax'd power iteration and the reference's vmapped one
+        # sum in different orders (different fusions), so the two f32 step
+        # sizes agree to a few ulps, not bit for bit.
+        MU_RTOL = 4 * np.finfo(np.float32).eps
         from repro.core.conjugates import make_task
         from repro.core.distributed import DistributedSparseCoder, DistConfig, make_debug_mesh
         from repro.core.dictionary import blocks_from_full
@@ -306,7 +327,7 @@ def test_hier_parity_with_reference_engine():
         mus = np.asarray(coder.adaptive_mu(Ws))
         assert mus.shape == (PODS * N,)
         assert float(np.ptp(mus)) == 0.0, mus
-        assert abs(float(mus[0]) - mu_ref) < 1e-7 * mu_ref, (mus[0], mu_ref)
+        assert abs(float(mus[0]) - mu_ref) < MU_RTOL * mu_ref, (mus[0], mu_ref)
 
         nu_ref, y_ref, _ = diffusion_infer(
             res, reg, W_blocks, x, jnp.asarray(A, jnp.float32),
@@ -469,6 +490,10 @@ def test_chain_3level_parity_with_reference_engine():
     1e-4."""
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
+        # The engine's pmax'd power iteration and the reference's vmapped one
+        # sum in different orders (different fusions), so the two f32 step
+        # sizes agree to a few ulps, not bit for bit.
+        MU_RTOL = 4 * np.finfo(np.float32).eps
         from repro.core.conjugates import make_task
         from repro.core.distributed import DistributedSparseCoder, DistConfig, make_debug_mesh
         from repro.core.dictionary import blocks_from_full
@@ -506,7 +531,7 @@ def test_chain_3level_parity_with_reference_engine():
         mus = np.asarray(coder.adaptive_mu(Ws))
         assert mus.shape == (NTOT,)
         assert float(np.ptp(mus)) == 0.0, mus
-        assert abs(float(mus[0]) - mu_ref) < 1e-7 * mu_ref
+        assert abs(float(mus[0]) - mu_ref) < MU_RTOL * mu_ref
 
         nu_ref, y_ref, _ = diffusion_infer(
             res, reg, W_blocks, x, chain.as_callable(), ones,

@@ -1,0 +1,87 @@
+"""Spans around the service's calls into the engine, taken from the
+benchmark's side: the service is handed this wrapper in place of its
+`DistributedSparseCoder` and sees the same object in every other respect.
+
+Each armed call is recorded with its start and end on the host clock
+(perf_counter), the dictionary version it ran against, and its input
+batch; the end is taken after the result is ready on the device, which the
+service waits for next in any case.  Spans also go into the profiler's
+trace as `bench.solve` / `bench.fit`, so a traced run can say what the host
+was doing while the device idled.
+"""
+
+from __future__ import annotations
+
+import threading
+import time
+import weakref
+from typing import Callable, Optional
+
+import jax
+import numpy as np
+
+
+class EngineProbe:
+    def __init__(self, coder, digest: Optional[Callable] = None, digest_fits: int = 0):
+        self._inner = coder
+        self._digest = digest
+        self._digest_fits = digest_fits
+        self._lock = threading.Lock()
+        self._versions = {}  # id(W) -> (weakref to W, version)
+        self._last_snapshot = None
+        self.armed = False
+        self.solves = []  # dicts: t0, t1, version, x (device array)
+        self.fits = []  # dicts: t0, t1, x (device array)
+        self.digests = []  # host W[:, cols] after fit 1, 2, ...
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def _version(self, W) -> int:
+        ref = self._versions.get(id(W))
+        if ref is None or ref[0]() is not W:
+            return -1
+        return ref[1]
+
+    def _register(self, W, version: int) -> None:
+        self._versions[id(W)] = (weakref.ref(W), version)
+
+    def arm(self) -> None:
+        """Start recording; the last published snapshot is version 0."""
+        self._register(self._last_snapshot, 0)
+        self.armed = True
+
+    def snapshot(self, W):
+        out = self._inner.snapshot(W)
+        self._last_snapshot = out
+        return out
+
+    @property
+    def solves_done(self) -> int:
+        return len(self.solves)
+
+    def solve(self, W, x, t0: int = 0):
+        if not self.armed:
+            return self._inner.solve(W, x, t0)
+        t_start = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.solve"):
+            out = jax.block_until_ready(self._inner.solve(W, x, t0))
+        t_end = time.perf_counter()
+        with self._lock:
+            self.solves.append(dict(t0=t_start, t1=t_end, version=self._version(W), x=x))
+        return out
+
+    def fit_batch(self, W, x, mu_w: float, t0: int = 0):
+        if not self.armed:
+            return self._inner.fit_batch(W, x, mu_w, t0)
+        t_start = time.perf_counter()
+        with jax.profiler.TraceAnnotation("bench.fit"):
+            out = jax.block_until_ready(self._inner.fit_batch(W, x, mu_w, t0))
+        t_end = time.perf_counter()
+        with self._lock:
+            version = len(self.fits) + 1
+            self.fits.append(dict(t0=t_start, t1=t_end, x=x))
+            self._register(out, version)
+            if self._digest is not None and version <= self._digest_fits:
+                self.digests.append(np.asarray(self._digest(out)))
+        return out

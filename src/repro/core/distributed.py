@@ -824,14 +824,26 @@ class DistributedSparseCoder:
     ) -> Tuple[Array, Array]:
         """Per-device dual solve: cfg.iters gossip iterations from nu = 0.
         `t0` (replicated int32 scalar) is the combiner-schedule origin of
-        the time-varying modes; every other mode ignores it."""
+        the time-varying modes; every other mode ignores it.  The phases
+        carry named scopes, so a profile groups the program's ops by them."""
+        with jax.named_scope("step_size"):
+            mu = self._mu_for(W_loc)
+        with jax.named_scope("dual_iterations"):
+            nu = self._dual_iterations(W_loc, x_loc, t0, mu)
+        y, _ = _local_code_and_back(self.res, self.reg, W_loc, nu, self.cfg)
+        return nu, y
+
+    def _dual_iterations(
+        self, W_loc: Array, x_loc: Array, t0: Array, mu: Array
+    ) -> Array:
+        """The solve's cfg.iters iterations of `cfg.mode` from nu = 0 at step
+        size `mu`; returns this device's dual estimate."""
         res, reg, cfg = self.res, self.reg, self.cfg
         ax = cfg.model_axis
         n_model, rank, theta, n_inf = self._iter_setup(W_loc, x_loc)
         nu0 = jnp.zeros_like(x_loc)
 
         if cfg.mode in ("exact", "exact_fista"):
-            mu = self._mu_for(W_loc)
 
             def total_grad(nu):
                 y, back = _local_code_and_back(res, reg, W_loc, nu, cfg)
@@ -859,7 +871,6 @@ class DistributedSparseCoder:
                 (nu, _), _ = jax.lax.scan(step, (nu0, nu0), None, length=cfg.iters)
 
         elif cfg.mode in RING_MODES:  # per-agent estimates + neighbor gossip
-            mu = self._mu_for(W_loc)
             beta = jnp.asarray(cfg.beta, x_loc.dtype)
             # ring exchanges need the static axis size (perms can't trace).
             nm = dist.axis_sizes(self.mesh)[ax]
@@ -911,7 +922,6 @@ class DistributedSparseCoder:
                 )
 
         elif cfg.mode in TV_MODES:  # time-varying combiner sequence
-            mu = self._mu_for(W_loc)
             scheds = self._gscheds
             local_grad = self._local_grad_fn(W_loc, x_loc, theta, n_inf, n_model)
             t_start = jnp.asarray(t0, jnp.int32)
@@ -954,7 +964,6 @@ class DistributedSparseCoder:
                 )
 
         elif cfg.mode in PUSH_MODES:  # push-sum ratio consensus (directed A)
-            mu = self._mu_for(W_loc)
             sched = self._gsched
             local_grad = self._local_grad_fn(W_loc, x_loc, theta, n_inf, n_model)
             # Ratio consensus (push-sum): a scalar weight w rides the wire
@@ -1002,7 +1011,6 @@ class DistributedSparseCoder:
                 )
 
         elif MODE_REGISTRY[cfg.mode].hierarchical:  # N-level chain gossip
-            mu = self._mu_for(W_loc)
             cs = self._csched
             local_grad = self._local_grad_fn(W_loc, x_loc, theta, n_inf, n_model)
             t_start = jnp.asarray(t0, jnp.int32)
@@ -1024,7 +1032,6 @@ class DistributedSparseCoder:
             )
 
         else:  # graph family: gossip under the compiled combiner schedule
-            mu = self._mu_for(W_loc)
             sched = self._gsched
             local_grad = self._local_grad_fn(W_loc, x_loc, theta, n_inf, n_model)
 
@@ -1072,8 +1079,7 @@ class DistributedSparseCoder:
                     step, (nu0, recv0), None, length=cfg.iters
                 )
 
-        y, _ = _local_code_and_back(res, reg, W_loc, nu, cfg)
-        return nu, y
+        return nu
 
     def _local_grad_fn(self, W_loc, x_loc, theta, n_inf, n_model):
         """Per-agent dual gradient grad J_k (shared by the ring and graph
@@ -1119,17 +1125,18 @@ class DistributedSparseCoder:
         gradient reduced over the data axes."""
         res, reg, cfg = self.res, self.reg, self.cfg
         nu, y = self._solve_body(W_loc, x_loc, t0)
-        # Minibatch-mean gradient nu^T y; reduce over the data axes (DP sync).
-        b_loc = jnp.asarray(x_loc.shape[0], x_loc.dtype)
-        g = nu.T @ y  # (M, K_loc)
-        for da in cfg.data_axes:
-            g = jax.lax.psum(g, da)
-            b_loc = jax.lax.psum(b_loc, da)
-        W_new = W_loc + mu_w * g / b_loc
-        if reg.nonneg:
-            W_new = jnp.maximum(W_new, 0.0)
-        norms = jnp.linalg.norm(W_new, axis=0, keepdims=True)
-        return W_new / jnp.maximum(norms, 1.0)
+        with jax.named_scope("atom_update"):
+            # Minibatch-mean gradient nu^T y; reduce over the data axes (DP sync).
+            b_loc = jnp.asarray(x_loc.shape[0], x_loc.dtype)
+            g = nu.T @ y  # (M, K_loc)
+            for da in cfg.data_axes:
+                g = jax.lax.psum(g, da)
+                b_loc = jax.lax.psum(b_loc, da)
+            W_new = W_loc + mu_w * g / b_loc
+            if reg.nonneg:
+                W_new = jnp.maximum(W_new, 0.0)
+            norms = jnp.linalg.norm(W_new, axis=0, keepdims=True)
+            return W_new / jnp.maximum(norms, 1.0)
 
     # -- novel-document scoring (exact aggregation = 1 psum) ---------------
 

@@ -351,7 +351,9 @@ def run(argv=None) -> dict:
         if drain_fut is not None:
             drain_info = drain_fut.result(timeout=600)
             print(f"drain applied: {drain_info}")
-        stats = svc.stats()
+    # read after stop(): it returns once the learner has fit every batch
+    # it was offered, so fit_steps is the run's whole count
+    stats = svc.stats()
     wall_s = time.perf_counter() - t0
 
     # Coding quality: for the l2-residual tasks nu* IS the fit residual

@@ -74,7 +74,6 @@ at the time its micro-batch is flushed — bounded staleness of at most
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import queue
 import threading
@@ -87,38 +86,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.distributed import DistributedSparseCoder
-from repro.runtime import dist
+from repro.runtime import dist, tracing
 
 Array = jax.Array
-
-
-class _CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling on the calling
-    thread, summed from jax.monitoring's compile-duration events (one
-    process-wide listener, installed on first use)."""
-
-    _local = threading.local()
-    _install_lock = threading.Lock()
-    _installed = False
-
-    @classmethod
-    def _listen(cls, event: str, duration: float, **_) -> None:
-        if event.startswith("/jax/core/compile/") and getattr(cls._local, "on", False):
-            cls._local.total += duration
-
-    @classmethod
-    @contextlib.contextmanager
-    def measure(cls, out: Dict[str, float], key: str):
-        with cls._install_lock:
-            if not cls._installed:
-                jax.monitoring.register_event_duration_secs_listener(cls._listen)
-                cls._installed = True
-        cls._local.on, cls._local.total = True, 0.0
-        try:
-            yield
-        finally:
-            cls._local.on = False
-            out[key] = cls._local.total
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,7 +242,7 @@ class DictionaryService:
         "submitted", "coded", "batches", "fit_steps", "fit_failures",
         "learn_dropped",
         "fit_first_error", "published", "grow_events", "drain_events",
-        "_latencies",
+        "_latencies", "_queue_waits",
         "_sched_t", "_coder", "_live", "_snap", "_comb_info",
         "_snap_version", "_serving_version",
     )
@@ -337,6 +307,11 @@ class DictionaryService:
         self.grow_events: List[Dict] = []
         self.drain_events: List[Dict] = []
         self._latencies = collections.deque(maxlen=cfg.latency_window)
+        # Per sample, submit -> its micro-batch's flush (seconds).
+        self._queue_waits = collections.deque(maxlen=cfg.latency_window)
+        # Spans of the batcher's and learner's work and the `compiles` the
+        # worker threads make (the recorder locks for itself).
+        self._trace = tracing.Recorder(counters=("compiles",))
         # Snapshot versioning for the serving plane (runtime/serving): the
         # version of the currently-published snapshot (0 = the initial one;
         # bumped by every publish — learner republish, install_snapshot,
@@ -408,10 +383,15 @@ class DictionaryService:
     def _solve_padded(self, coder, snap, xb: np.ndarray):
         """Code a real batch of b rows against `snap`."""
         b = xb.shape[0]
-        with self._exec_lock:
-            t0 = self._advance_schedule(coder)
-            nu, y = coder.solve(snap, jnp.asarray(self._pad_rows(xb), jnp.float32), t0)
-            nu, y = np.asarray(nu), np.asarray(y)
+        # the wait span ends where the lock is taken
+        with self._trace.span("service.exec_wait.solve") as waiting, self._exec_lock:
+            waiting.close()
+            with self._trace.span("service.exec.solve", batch=b):
+                t0 = self._advance_schedule(coder)
+                x = jnp.asarray(self._pad_rows(xb), jnp.float32)
+                with self._trace.span("engine.solve"):
+                    nu, y = jax.block_until_ready(coder.solve(snap, x, t0))
+                nu, y = np.asarray(nu), np.asarray(y)
         return nu[:b], y[:b]
 
     # -- lifecycle --------------------------------------------------------
@@ -427,10 +407,10 @@ class DictionaryService:
         holding the lock (threading.Lock is not reentrant)."""
         secs: Dict[str, float] = {}
         z = jnp.zeros((self._pad, self._m), jnp.float32)
-        with _CompileClock.measure(secs, "solve"):
+        with tracing.compile_seconds(secs, "solve"):
             jax.block_until_ready(coder.solve(W, z))  # analyze: allow(exec-lock)
         if self.cfg.learn:
-            with _CompileClock.measure(secs, "fit"):
+            with tracing.compile_seconds(secs, "fit"):
                 jax.block_until_ready(coder.fit_batch(W, z, 0.0))  # analyze: allow(exec-lock)
         return secs
 
@@ -446,12 +426,20 @@ class DictionaryService:
             self.compile_s = self._warmup(self._coder, self._snap)
         self._t_start = time.perf_counter()
         self._threads = [
-            threading.Thread(target=self._batcher_loop, name="dict-batcher", daemon=True),
-            threading.Thread(target=self._learner_loop, name="dict-learner", daemon=True),
+            threading.Thread(target=self._counting_compiles, args=(self._batcher_loop,),
+                             name="dict-batcher", daemon=True),
+            threading.Thread(target=self._counting_compiles, args=(self._learner_loop,),
+                             name="dict-learner", daemon=True),
         ]
         for t in self._threads:
             t.start()
         return self
+
+    def _counting_compiles(self, loop) -> None:
+        """A worker thread's body: `loop`, with every compile it makes
+        counted (steady-state serving should make none)."""
+        with self._trace.counting_compiles():
+            loop()
 
     def stop(self) -> None:
         """Drain the queues (every submitted sample is coded — single-pass
@@ -657,8 +645,10 @@ class DictionaryService:
         execution starts from, and the hier pod_topology /
         pod_gossip_every)."""
         elapsed = (time.perf_counter() - self._t_start) if self._t_start else 0.0
+        trace = self._trace.snapshot()
         with self._lock:  # one consistent snapshot of every counter
             lat = np.asarray(self._latencies, np.float64)
+            waits = np.asarray(self._queue_waits, np.float64)
             out = {
                 "submitted": self.submitted,
                 "coded": self.coded,
@@ -700,13 +690,19 @@ class DictionaryService:
                 "elapsed_s": elapsed,
                 "samples_per_s": (self.coded / elapsed) if elapsed > 0 else 0.0,
             }
-        if lat.size:
-            out["latency_ms"] = {
-                "p50": float(np.percentile(lat, 50) * 1e3),
-                "p95": float(np.percentile(lat, 95) * 1e3),
-                "p99": float(np.percentile(lat, 99) * 1e3),
-                "max": float(lat.max() * 1e3),
-            }
+        # Spans of the worker threads (service.collect, service.exec_wait.*,
+        # service.exec.*, engine.*, service.resolve) and the compiles they
+        # made after start().
+        out["spans"] = trace["spans"]
+        out["counters"] = trace["counters"]
+        for key, secs in (("latency_ms", lat), ("queue_wait_ms", waits)):
+            if secs.size:
+                out[key] = {
+                    "p50": float(np.percentile(secs, 50) * 1e3),
+                    "p95": float(np.percentile(secs, 95) * 1e3),
+                    "p99": float(np.percentile(secs, 99) * 1e3),
+                    "max": float(secs.max() * 1e3),
+                }
         return out
 
     # -- worker loops -----------------------------------------------------
@@ -719,15 +715,16 @@ class DictionaryService:
             items.append(self._queue.get(timeout=0.01))
         except queue.Empty:
             return items
-        deadline = time.perf_counter() + self.cfg.max_wait_s
-        while len(items) < self.cfg.micro_batch:
-            left = deadline - time.perf_counter()
-            if left <= 0:
-                break
-            try:
-                items.append(self._queue.get(timeout=left))
-            except queue.Empty:
-                break
+        with self._trace.span("service.collect"):
+            deadline = time.perf_counter() + self.cfg.max_wait_s
+            while len(items) < self.cfg.micro_batch:
+                left = deadline - time.perf_counter()
+                if left <= 0:
+                    break
+                try:
+                    items.append(self._queue.get(timeout=left))
+                except queue.Empty:
+                    break
         return items
 
     def _batcher_loop(self) -> None:
@@ -737,6 +734,7 @@ class DictionaryService:
                 if self._stop.is_set() and self._queue.empty():
                     return
                 continue
+            t_flush = time.perf_counter()
             xb = np.stack([it.x for it in items])
             with self._lock:
                 coder, snap, ver = self._coder, self._snap, self._snap_version
@@ -746,26 +744,28 @@ class DictionaryService:
                 for it in items:
                     _resolve(it.future, exc=e)
                 continue
-            dropped = False
-            if self.cfg.learn:
-                # learner lagging past the cap: the reservoir evicts a
-                # uniform victim (and counts it) rather than stalling coding
-                # or letting staleness/memory grow without bound
-                dropped = self._learn_q.offer(xb)
-            # Account BEFORE resolving futures: a client woken by the last
-            # result may immediately read stats() and must see this batch
-            # counted (and must not observe _latencies mid-append).
-            t_done = time.perf_counter()
-            with self._lock:
-                for it in items:
-                    self._latencies.append(t_done - it.t_submit)
-                self.coded += len(items)
-                self.batches += 1
-                self._serving_version = ver
-                if dropped:
-                    self.learn_dropped += 1
-            for i, it in enumerate(items):
-                _resolve(it.future, (nu[i], y[i]))
+            with self._trace.span("service.resolve"):
+                dropped = False
+                if self.cfg.learn:
+                    # learner lagging past the cap: the reservoir evicts a
+                    # uniform victim (and counts it) rather than stalling
+                    # coding or letting staleness/memory grow without bound
+                    dropped = self._learn_q.offer(xb)
+                # Account BEFORE resolving futures: a client woken by the
+                # last result may immediately read stats() and must see this
+                # batch counted (and must not observe _latencies mid-append).
+                t_done = time.perf_counter()
+                with self._lock:
+                    for it in items:
+                        self._latencies.append(t_done - it.t_submit)
+                        self._queue_waits.append(t_flush - it.t_submit)
+                    self.coded += len(items)
+                    self.batches += 1
+                    self._serving_version = ver
+                    if dropped:
+                        self.learn_dropped += 1
+                for i, it in enumerate(items):
+                    _resolve(it.future, (nu[i], y[i]))
 
     def _learner_loop(self) -> None:
         while True:
@@ -793,18 +793,21 @@ class DictionaryService:
             # sum; rescale mu_w so the minibatch mean is over REAL samples.
             mu_w_eff = self.cfg.mu_w * (xb.shape[0] / b)
             try:
-                with self._exec_lock:
-                    t0 = self._advance_schedule(coder)
-                    try:
-                        live2 = coder.fit_batch(
-                            live, jnp.asarray(xb, jnp.float32), mu_w_eff, t0
-                        )
-                        jax.block_until_ready(live2)
-                    except Exception:
-                        # the claimed window never ran: hand it back so the
-                        # schedule clock only counts real executions
-                        self._rollback_schedule(coder)
-                        raise
+                # the wait span ends where the lock is taken
+                with self._trace.span("service.exec_wait.fit") as waiting, self._exec_lock:
+                    waiting.close()
+                    with self._trace.span("service.exec.fit", fit=self.fit_steps + 1):
+                        t0 = self._advance_schedule(coder)
+                        try:
+                            x = jnp.asarray(xb, jnp.float32)
+                            with self._trace.span("engine.fit"):
+                                live2 = coder.fit_batch(live, x, mu_w_eff, t0)
+                                jax.block_until_ready(live2)
+                        except Exception:
+                            # the claimed window never ran: hand it back so
+                            # the schedule clock only counts real executions
+                            self._rollback_schedule(coder)
+                            raise
             except Exception as e:
                 # A failed fit step must never take down serving, but it
                 # must not be invisible either: count it and keep the first

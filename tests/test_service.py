@@ -360,6 +360,79 @@ def test_service_lifecycle_grow_then_drain(family):
     assert "OK" in out
 
 
+# -- spans under a profiler (one CPU device, in this process) ----------------
+
+
+SERVICE_SPANS = ("service.collect", "service.exec_wait.solve", "service.exec.solve",
+                 "engine.solve", "service.resolve", "service.exec_wait.fit",
+                 "service.exec.fit", "engine.fit")
+
+
+def test_service_spans_land_in_the_profiler_trace(tmp_path):
+    """A tiny learning service under jax.profiler.trace: every span is on a
+    host line of the trace, each engine.solve lies inside a
+    service.exec.solve on its line, one engine.solve per coded batch, and
+    serving after the warm-up compiled nothing."""
+    import glob
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.conjugates import make_task
+    from repro.core.distributed import DistConfig, DistributedSparseCoder
+    from repro.runtime import dist
+    from repro.runtime.service import DictionaryService, ServiceConfig
+
+    res, reg = make_task("sparse_svd", gamma=0.25, delta=0.05)
+    mesh = dist.make_mesh((1, 1), (dist.DATA_AXIS, dist.MODEL_AXIS))
+    M, K = 8, 16
+    W0 = jax.random.normal(jax.random.PRNGKey(0), (M, K))
+    W0 = W0 / jnp.linalg.norm(W0, axis=0)
+    coder = DistributedSparseCoder(mesh, res, reg, DistConfig(mode="exact_fista", iters=20))
+    X = np.random.default_rng(0).normal(size=(18, M)).astype(np.float32)
+    svc = DictionaryService(coder, W0, ServiceConfig(micro_batch=4, max_wait_s=0.01, mu_w=0.1))
+    svc.start()
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            for f in [svc.submit(x) for x in X]:
+                f.result(timeout=120)
+            deadline = time.monotonic() + 120
+            while svc.stats()["fit_steps"] < svc.stats()["batches"]:
+                assert time.monotonic() < deadline, "the learner did not catch up"
+                time.sleep(0.01)
+            stats = svc.stats()
+    finally:
+        svc.stop()
+
+    assert stats["coded"] == 18 and stats["batches"] >= 5
+    assert stats["counters"]["compiles"] == 0
+    assert stats["spans"]["engine.solve"]["count"] == stats["batches"]
+    assert stats["spans"]["engine.fit"]["count"] == stats["fit_steps"]
+    assert set(stats["queue_wait_ms"]) == {"p50", "p95", "p99", "max"}
+
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert path, "the profiler wrote no trace"
+    pd = jax.profiler.ProfileData.from_file(path[0])
+    lines = []  # per host line: name -> [(start, end)]
+    for plane in pd.planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                ev = {}
+                for e in line.events:
+                    ev.setdefault(e.name, []).append((e.start_ns, e.start_ns + e.duration_ns))
+                lines.append(ev)
+    seen = {name for ev in lines for name in ev}
+    assert set(SERVICE_SPANS) <= seen, sorted(set(SERVICE_SPANS) - seen)
+    solves = 0
+    for ev in lines:
+        for t0, t1 in ev.get("engine.solve", []):
+            solves += 1
+            assert any(a <= t0 and t1 <= b for a, b in ev.get("service.exec.solve", []))
+    assert solves == stats["batches"]
+
+
 # -- reservoir backpressure (fast: the reservoir is pure host code) ---------
 
 

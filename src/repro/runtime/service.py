@@ -67,8 +67,13 @@ exactly that contract:
     recompile (fixed micro-batch shape).
 
 Consistency model: a sample's code reflects the newest snapshot published
-at the time its micro-batch is flushed — bounded staleness of at most
-`publish_every` fit steps plus one in-flight batch, never a torn read.
+at the time its micro-batch's solve takes the exec lock (the batcher reads
+the snapshot under that lock, and the learner publishes a fit before it
+releases the lock) — bounded staleness of at most `publish_every` fit
+steps plus one in-flight batch, never a torn read.  So with
+`publish_every=1` a learned batch is coded and fitted against the same
+dictionary, and the fit reuses the batch's codes (the engine's
+`fits_reused`) instead of solving it again.
 """
 
 from __future__ import annotations
@@ -133,6 +138,25 @@ def _resolve(fut: Future, result=None, exc: Optional[BaseException] = None) -> N
             fut.set_result(result)
     except Exception:
         pass  # already cancelled/resolved by the client
+
+
+class _LearnBatch:
+    """One coded micro-batch offered to the learner: its real rows, and
+    the padded device batch the solve coded (`x`, None once newer batches
+    took its place), which the fit passes on so the engine can reuse the
+    solve's codes."""
+
+    __slots__ = ("rows", "x")
+
+    def __init__(self, rows: np.ndarray, x: Optional[Array]):
+        self.rows = rows
+        self.x = x
+
+
+# Learn batches that keep their device batch: the newest two, enough for a
+# batcher that takes the exec lock twice in a row, so the reservoir's
+# device memory stays bounded however far the learner lags.
+_DEVICE_BATCHES = 2
 
 
 class _LearnReservoir:
@@ -276,6 +300,9 @@ class DictionaryService:
         self._pad = self._pad_target(coder)
         self._queue: "queue.Queue[_Item]" = queue.Queue(maxsize=cfg.queue_capacity)
         self._learn_q = _LearnReservoir(cfg.learn_queue_cap, cfg.learn_seed)
+        # The offered learn batches that still hold a device batch (the
+        # batcher's alone), oldest first.
+        self._device_batches: "collections.deque[_LearnBatch]" = collections.deque()
         self._grow_q: "queue.Queue[Tuple[int, jax.Array, Optional[Tuple], Future]]" = queue.Queue()
         self._drain_q: "queue.Queue[Tuple[Tuple[int, ...], Future]]" = queue.Queue()
         self._stop = threading.Event()
@@ -311,7 +338,10 @@ class DictionaryService:
         self._queue_waits = collections.deque(maxlen=cfg.latency_window)
         # Spans of the batcher's and learner's work and the `compiles` the
         # worker threads make (the recorder locks for itself).
-        self._trace = tracing.Recorder(counters=("compiles",))
+        # `fits_reused` and `fits_resolved` count the learner's fits by
+        # whether the engine reused the batch's codes.
+        self._trace = tracing.Recorder(
+            counters=("compiles", "fits_reused", "fits_resolved"))
         # Snapshot versioning for the serving plane (runtime/serving): the
         # version of the currently-published snapshot (0 = the initial one;
         # bumped by every publish — learner republish, install_snapshot,
@@ -380,19 +410,34 @@ class DictionaryService:
         with self._lock:
             self._sched_t -= coder.cfg.iters
 
-    def _solve_padded(self, coder, snap, xb: np.ndarray):
-        """Code a real batch of b rows against `snap`."""
+    def _solve_padded(self, xb: np.ndarray):
+        """Code a real batch of b rows against the published snapshot,
+        read once the exec lock is held (so no fit can publish a newer one
+        before the solve runs).  Returns (nu, y, the padded device batch,
+        the snapshot's version)."""
         b = xb.shape[0]
         # the wait span ends where the lock is taken
         with self._trace.span("service.exec_wait.solve") as waiting, self._exec_lock:
             waiting.close()
             with self._trace.span("service.exec.solve", batch=b):
+                with self._lock:
+                    coder, snap, ver = self._coder, self._snap, self._snap_version
                 t0 = self._advance_schedule(coder)
                 x = jnp.asarray(self._pad_rows(xb), jnp.float32)
                 with self._trace.span("engine.solve"):
                     nu, y = jax.block_until_ready(coder.solve(snap, x, t0))
                 nu, y = np.asarray(nu), np.asarray(y)
-        return nu[:b], y[:b]
+        return nu[:b], y[:b], x, ver
+
+    def _offer_learn(self, xb: np.ndarray, x: Array) -> bool:
+        """Offer a coded batch to the learner (see `_LearnReservoir.offer`);
+        a batch older than the newest `_DEVICE_BATCHES` drops its device
+        copy, and the learner uploads its rows again if it ever fits it."""
+        batch = _LearnBatch(xb, x)
+        self._device_batches.append(batch)
+        if len(self._device_batches) > _DEVICE_BATCHES:
+            self._device_batches.popleft().x = None
+        return self._learn_q.offer(batch)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -736,10 +781,8 @@ class DictionaryService:
                 continue
             t_flush = time.perf_counter()
             xb = np.stack([it.x for it in items])
-            with self._lock:
-                coder, snap, ver = self._coder, self._snap, self._snap_version
             try:
-                nu, y = self._solve_padded(coder, snap, xb)
+                nu, y, x, ver = self._solve_padded(xb)
             except Exception as e:  # resolve futures so clients never hang
                 for it in items:
                     _resolve(it.future, exc=e)
@@ -750,7 +793,7 @@ class DictionaryService:
                     # learner lagging past the cap: the reservoir evicts a
                     # uniform victim (and counts it) rather than stalling
                     # coding or letting staleness/memory grow without bound
-                    dropped = self._learn_q.offer(xb)
+                    dropped = self._offer_learn(xb, x)
                 # Account BEFORE resolving futures: a client woken by the
                 # last result may immediately read stats() and must see this
                 # batch counted (and must not observe _latencies mid-append).
@@ -772,7 +815,7 @@ class DictionaryService:
             self._maybe_grow()
             self._maybe_drain()
             try:
-                xb = self._learn_q.take(timeout=0.02)
+                batch = self._learn_q.take(timeout=0.02)
             except queue.Empty:
                 # Exit only once the batcher has EXITED (not merely an empty
                 # queue — it may be mid-solve, about to enqueue the final
@@ -785,21 +828,24 @@ class DictionaryService:
                 ):
                     return
                 continue
-            with self._lock:
-                coder, live = self._coder, self._live
-            b = xb.shape[0]
-            xb = self._pad_rows(xb)
-            # Zero pad rows code to nu=0 so they add nothing to the gradient
-            # sum; rescale mu_w so the minibatch mean is over REAL samples.
-            mu_w_eff = self.cfg.mu_w * (xb.shape[0] / b)
+            b = batch.rows.shape[0]
+            x = batch.x
             try:
                 # the wait span ends where the lock is taken
                 with self._trace.span("service.exec_wait.fit") as waiting, self._exec_lock:
                     waiting.close()
                     with self._trace.span("service.exec.fit", fit=self.fit_steps + 1):
+                        with self._lock:
+                            coder, live = self._coder, self._live
                         t0 = self._advance_schedule(coder)
                         try:
-                            x = jnp.asarray(xb, jnp.float32)
+                            if x is None:
+                                x = jnp.asarray(self._pad_rows(batch.rows), jnp.float32)
+                            # Zero pad rows code to nu=0 so they add nothing
+                            # to the gradient sum; rescale mu_w so the
+                            # minibatch mean is over REAL samples.
+                            mu_w_eff = self.cfg.mu_w * (x.shape[0] / b)
+                            reused = coder.fits_reused
                             with self._trace.span("engine.fit"):
                                 live2 = coder.fit_batch(live, x, mu_w_eff, t0)
                                 jax.block_until_ready(live2)
@@ -808,6 +854,11 @@ class DictionaryService:
                             # the schedule clock only counts real executions
                             self._rollback_schedule(coder)
                             raise
+                        # Published before the exec lock is released, so
+                        # the next solve codes against this fit's W.
+                        self._publish_fit(coder, live2)
+                        self._trace.count("fits_reused" if coder.fits_reused > reused
+                                          else "fits_resolved")
             except Exception as e:
                 # A failed fit step must never take down serving, but it
                 # must not be invisible either: count it and keep the first
@@ -816,16 +867,19 @@ class DictionaryService:
                     self.fit_failures += 1
                     if self.fit_first_error is None:
                         self.fit_first_error = repr(e)
-                continue
-            with self._lock:
-                self.fit_steps += 1
-                # only publish if no growth swapped the coder underneath us
-                if self._coder is coder:
-                    self._live = live2
-                    if self.fit_steps % self.cfg.publish_every == 0:
-                        self._snap = live2
-                        self.published += 1
-                        self._snap_version += 1
+
+    def _publish_fit(self, coder, live2: Array) -> None:
+        """Count a fit step and make its W the live copy, and the published
+        snapshot every `publish_every` steps."""
+        with self._lock:
+            self.fit_steps += 1
+            # only publish if no growth swapped the coder underneath us
+            if self._coder is coder:
+                self._live = live2
+                if self.fit_steps % self.cfg.publish_every == 0:
+                    self._snap = live2
+                    self.published += 1
+                    self._snap_version += 1
 
     def _maybe_grow(self) -> None:
         try:

@@ -7,6 +7,7 @@ import threading
 import time
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -133,22 +134,29 @@ def test_compile_seconds_sums_the_blocks_compiles():
 
 
 @pytest.fixture(scope="module")
-def lowered():
+def coder():
     from repro.core.conjugates import make_task
     from repro.core.distributed import DistConfig, DistributedSparseCoder
     from repro.runtime import dist
 
     res, reg = make_task("sparse_svd", gamma=0.25, delta=0.05)
     mesh = dist.make_mesh((1, 1), (dist.DATA_AXIS, dist.MODEL_AXIS))
-    coder = DistributedSparseCoder(mesh, res, reg, DistConfig(mode="exact_fista", iters=5))
-    W, x, t0 = jnp.zeros((8, 16)), jnp.zeros((4, 8)), jnp.int32(0)
-    return {"solve": coder._solve.lower(W, x, t0),
-            "fit": coder._fit.lower(W, x, jnp.float32(0.1), t0)}
+    return DistributedSparseCoder(mesh, res, reg, DistConfig(mode="exact_fista", iters=5))
+
+
+# W (M=8, K=16), a batch x of 4 rows, its duals nu (4, M) and y (4, K)
+W, X, NU, Y = jnp.zeros((8, 16)), jnp.zeros((4, 8)), jnp.zeros((4, 8)), jnp.zeros((4, 16))
+
+
+@pytest.fixture(scope="module")
+def lowered(coder):
+    return {"solve": coder._solve.lower(W, X, jnp.int32(0)),
+            "fit": coder._fit.lower(W, NU, Y, jnp.float32(0.1))}
 
 
 @pytest.mark.parametrize("program,module,scopes", [
     ("solve", "jit__solve_body", ("step_size", "dual_iterations")),
-    ("fit", "jit__fit_body", ("step_size", "dual_iterations", "atom_update")),
+    ("fit", "jit__fit_body", ("atom_update",)),
 ])
 def test_program_module_names_and_phase_scopes(lowered, program, module, scopes):
     """A trace finds the solve and the fit by these module names; a rename
@@ -160,3 +168,28 @@ def test_program_module_names_and_phase_scopes(lowered, program, module, scopes)
         assert f"/{scope}/" in meta, scope
     if program == "solve":
         assert "atom_update" not in meta
+    else:
+        assert "dual_iterations" not in meta and "step_size" not in meta
+
+
+def _primitives(jaxpr) -> set:
+    """Names of every primitive in `jaxpr` and the jaxprs nested in it."""
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else (param,):
+                inner = getattr(sub, "jaxpr", sub)
+                if isinstance(inner, jax.extend.core.Jaxpr):
+                    names |= _primitives(inner)
+    return names
+
+
+def test_fit_program_iterates_nothing(coder):
+    """The fit program is the atom update alone: it takes the duals a solve
+    returned, so it holds no loop that could solve the batch again."""
+    fit = _primitives(jax.make_jaxpr(coder._fit)(W, NU, Y, jnp.float32(0.1)).jaxpr)
+    solve = _primitives(jax.make_jaxpr(coder._solve)(W, X, jnp.int32(0)).jaxpr)
+    assert "shard_map" in fit and "dot_general" in fit
+    assert "scan" in solve  # the walk does find the solve's iterations
+    assert not fit & {"scan", "while"}, sorted(fit)

@@ -537,7 +537,8 @@ def run(root: pathlib.Path = REPO) -> List[Finding]:
             agent_axes = frozenset(coder._agent_axes)
             data_axes = frozenset(case.cfg.data_axes)
             in_varying = (
-                [agent_axes, data_axes, frozenset(), frozenset()] if fit
+                [agent_axes, agent_axes | data_axes, agent_axes | data_axes,
+                 frozenset()] if fit
                 else [agent_axes, data_axes, frozenset()]
             )
             checker = check_jaxpr(
@@ -546,8 +547,8 @@ def run(root: pathlib.Path = REPO) -> List[Finding]:
             findings.extend(checker.findings)
             if fit:
                 continue
-            # wire-byte cross-check (solve body only: fit = solve + one
-            # out-of-scan data-axis psum, same per-iteration bytes)
+            # wire-byte cross-check (solve body only: the fit body is the
+            # atom update, one data-axis psum and no iterations)
             b_loc = batch // int(
                 math.prod(sizes[a] for a in case.cfg.data_axes)
             )

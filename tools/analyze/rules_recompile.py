@@ -410,10 +410,12 @@ def collect_compiled(root: pathlib.Path = REPO):
             (Ws, xs1, t(0, jnp.int32)), (Ws, xs2, t(7, jnp.int32)),
             label=f"{label}:solve", file=file, root=root,
         ))
+        nu1, y1 = coder._solve(Ws, xs1, t(0, jnp.int32))
+        nu2, y2 = coder._solve(Ws, xs2, t(7, jnp.int32))
         findings.extend(assert_no_retrace(
             coder._fit,
-            (Ws, xs1, t(0.05, jnp.float32), t(0, jnp.int32)),
-            (Ws, xs2, t(0.1, jnp.float32), t(3, jnp.int32)),
+            (Ws, nu1, y1, t(0.05, jnp.float32)),
+            (Ws, nu2, y2, t(0.1, jnp.float32)),
             label=f"{label}:fit", file=file, root=root,
         ))
         records[label] = {

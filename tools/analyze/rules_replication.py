@@ -312,7 +312,10 @@ def run(root: pathlib.Path = REPO) -> List[Finding]:
             if program == "mu":
                 in_varying = [agent_axes]
             elif program == "fit":
-                in_varying = [agent_axes, data_axes, frozenset(), frozenset()]
+                # W, then the solve's duals nu and y (per agent and per
+                # data shard), then mu_w
+                duals = agent_axes | data_axes
+                in_varying = [agent_axes, duals, duals, frozenset()]
             else:
                 in_varying = [agent_axes, data_axes, frozenset()]
             meta = coder.out_spec_meta[program]

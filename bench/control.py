@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
-"""The control of the comparison that decides `correct`: the reference
-put in the program's place, computed with products at the platform's HIGH
+"""The control of the comparison that decides `correct`, as the
+configuration's kind makes it (`control` of bench/kinds/<kind>.py; the
+coding kind's is described here): the reference put in the program's
+place, computed with products at the platform's HIGH
 precision (three bfloat16 passes, the precision just below the
 float32-at-HIGHEST the configurations state), then compared with the
 reference at HIGHEST by the very numbers, limits and verdict a benchmark
@@ -29,38 +31,26 @@ import sys
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
 import run
-import traffic
-from reference import compare, fit, judge, solve, solver_args, verdict
+from reference import judge, verdict
 
 
 def control_numbers(cfg: dict, mix: dict, seed: int, devices, matmul: str = "high") -> dict:
+    """The numbers of the configuration's kind's control (`control` of
+    bench/kinds/<kind>.py) for one seed: W0 and the digested atoms as a run
+    makes them, and as many fit steps followed as a run follows."""
     data, model = cfg["mesh"]
     mesh = Mesh(np.asarray(devices).reshape(data, model), ("data", "model"))
-    W0_fn = run.w0_maker(cfg, mesh, seed)
+    kind = run.kind_of(cfg)
+    if not hasattr(kind, "control"):
+        raise SystemExit(f"control: kind {cfg.get('kind', run.DEFAULT_KIND)!r} of "
+                         f"{cfg['name']} has no control")
     cols, _ = run.digest_atoms(cfg, seed)
-    stream = traffic.Stream(cfg["m"], cfg["atoms"], mix, seed)
     follow = run.FOLLOW_FITS if mix["learn"] else 0
-    per = run.CHECK_ROWS // (follow + 1)
-    args = solver_args(cfg, matmul)
-    W = W0_fn()
-    sampled, fits, digests = {}, [], [None]
-    for v in range(follow + 1):
-        x = np.stack([stream.next() for _ in range(per)])
-        nu, y = solve(W, jnp.asarray(np.concatenate(
-            [x, np.zeros((run.CHECK_ROWS - per, cfg["m"]), np.float32)])), **args)
-        sampled[v] = {"x": x, "nu": np.asarray(nu)[:per], "y": np.asarray(y)[:per]}
-        if v < follow:
-            xb = np.stack([stream.next() for _ in range(cfg["micro_batch"])])
-            fits.append(xb)
-            W = fit(W, jnp.asarray(xb), float(cfg["micro_batch"]), cfg["mu_w"], **args)
-            digests.append(np.asarray(W[:, cols]))
-    del W
-    return compare(cfg, W0_fn, sampled, fits, cols, digests, run.CHECK_ROWS)
+    return kind.control(cfg, mix, seed, kind.w0(cfg, mesh, seed), cols, follow, matmul)
 
 
 def main() -> int:

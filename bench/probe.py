@@ -8,10 +8,16 @@ batch; the end is taken after the result is ready on the device, which the
 service waits for next in any case.  Spans also go into the profiler's
 trace as `bench.solve` / `bench.fit`, so a traced run can say what the host
 was doing while the device idled.
+
+`gate` is held by the benchmark's client while it submits a sample and
+attaches the sample's done callback.  A solve passes through it before it
+starts, so a solve that holds a sample waits until that sample's callback
+is in place, and the callback runs in the batcher as the sample resolves.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
@@ -21,12 +27,48 @@ import jax
 import numpy as np
 
 
+class Gate:
+    """Keeps a solve from starting while the client is between submitting a
+    sample and attaching its callback, and lets a solve that waits go before
+    the client's next sample, so a burst of submissions does not hold the
+    batcher back for longer than one sample takes."""
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._held = False
+        self._waiting = 0
+
+    @contextlib.contextmanager
+    def held(self):
+        """Around one submission and the attaching of its callbacks."""
+        with self._cond:
+            while self._waiting:
+                self._cond.wait()
+            self._held = True
+        try:
+            yield
+        finally:
+            with self._cond:
+                self._held = False
+                self._cond.notify_all()
+
+    def pass_through(self) -> None:
+        """Before a solve: wait for the submission in progress, if any."""
+        with self._cond:
+            self._waiting += 1
+            while self._held:
+                self._cond.wait()
+            self._waiting -= 1
+            self._cond.notify_all()
+
+
 class EngineProbe:
     def __init__(self, coder, digest: Optional[Callable] = None, digest_fits: int = 0):
         self._inner = coder
         self._digest = digest
         self._digest_fits = digest_fits
         self._lock = threading.Lock()
+        self.gate = Gate()
         self._versions = {}  # id(W) -> (weakref to W, version)
         self._last_snapshot = None
         self.armed = False
@@ -61,6 +103,7 @@ class EngineProbe:
         return len(self.solves)
 
     def solve(self, W, x, t0: int = 0):
+        self.gate.pass_through()
         if not self.armed:
             return self._inner.solve(W, x, t0)
         t_start = time.perf_counter()

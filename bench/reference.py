@@ -1,5 +1,6 @@
-"""Plain reference of the deployment's arithmetic, and the comparison that
-decides `correct`.  Imports nothing of the program.
+"""Plain reference arithmetic, and the comparisons that decide `correct`.
+Imports nothing of the program.  A kind (`bench/kinds/<kind>.py`) builds
+its check from these; each kind states the (task, mode) pairs it follows.
 
 The dual problem of a sparse_svd task (l2 residual, elastic net with
 gamma, delta), solved by exact_fista: N agents each hold an atom block W_k;
@@ -124,7 +125,8 @@ def change_gap(w0: np.ndarray, w_prog: np.ndarray, w_ref: np.ndarray) -> Optiona
     return float(gap.max())
 
 
-def _pad(x: np.ndarray, rows: int) -> np.ndarray:
+def pad(x: np.ndarray, rows: int) -> np.ndarray:
+    """x with zero rows appended up to `rows`."""
     return np.concatenate([x, np.zeros((rows - len(x), x.shape[1]), x.dtype)])
 
 
@@ -155,37 +157,3 @@ def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict[str, dict
 def verdict(checks: Dict[str, dict]) -> bool:
     """`correct`: every number compared is at or under its limit."""
     return all(c["value"] <= c["limit"] for c in checks.values())
-
-
-def compare(cfg: dict, W0_fn, sampled: Dict[int, dict], fits: list, cols: np.ndarray,
-            w_digests: list, rows: int, matmul: str = "highest") -> Dict[str, float]:
-    """Follow the program's first fits with the reference, and compare.
-
-    `sampled` maps a dictionary version v to the served samples coded
-    against it: {"x": (n, M), "nu": (n, M), "y": (n, K)}.  `fits` holds the
-    input of the program's fit steps 1..F as the engine got it (padded);
-    the reference fits the real rows it finds there, mean over their count.
-    `w_digests` the program's W[:, cols] after each of them (index 0 is
-    W0's).  Samples are solved `rows` at a time (one compiled shape).
-    Returns the numbers compared, each the worst over its set."""
-    args = solver_args(cfg, matmul)
-    W = W0_fn()
-    w0_cols = np.asarray(W[:, cols])
-    out = {"nu_gap": 0.0, "y_gap": 0.0}
-    n_follow = len(fits)
-    for v in range(n_follow + 1):
-        if v in sampled:
-            s = sampled[v]
-            n = len(s["x"])
-            nu, y = solve(W, jnp.asarray(_pad(s["x"], rows)), **args)
-            out["nu_gap"] = max(out["nu_gap"], row_gap(s["nu"], np.asarray(nu)[:n]))
-            out["y_gap"] = max(out["y_gap"], row_gap(s["y"], np.asarray(y)[:n]))
-        if v < n_follow:
-            xb = fits[v]
-            real = real_rows(xb)
-            W = fit(W, jnp.asarray(_pad(real, len(xb))), float(len(real)), cfg["mu_w"],
-                    **args)
-    if n_follow:
-        gap = change_gap(w0_cols, w_digests[n_follow], np.asarray(W[:, cols]))
-        out["w_change_gap"] = 1.0 if gap is None else gap
-    return out

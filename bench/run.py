@@ -5,25 +5,35 @@
 
 A cell (an entry of `workloads` in BENCHMARK.json) names a configuration,
 `bench/configs/<config>.json`, and a traffic mix, `bench/traffic/<mix>.json`;
-each metric is read by `bench/metrics/<metric>.py`.  Nothing here knows a
-cell, a configuration, a mix or a metric by name.
+each metric is read by `bench/metrics/<metric>.py`.  The configuration's
+`kind` (default "coding") names `bench/kinds/<kind>.py`, which brings what
+is the deployment's own: W0, the requests, how a request is submitted, and
+the reference and numbers that decide `correct` (see kinds/coding.py).
+Every other configuration key that names a field of the engine's
+`DistConfig`, the service's `ServiceConfig` or a parameter of `make_task`
+is passed to it as it stands (`max_wait_ms` as `max_wait_s`).  Nothing
+here knows a cell, a configuration, a kind, a mix or a metric by name.
 
 The run drives the program's own serving path: `DictionaryService`
 (runtime/service.py) over `DistributedSparseCoder` (core/distributed.py),
-fed by a client thread that submits samples as the mix says.
+fed by a client thread that submits the kind's requests as the mix says.
+A request is n >= 1 samples due at one moment; every time and count below
+is per sample.
 
   set-up   process start to the window's open: JAX and the chip, the mesh,
-           W0 drawn on the device from the seed in the engine's sharding,
+           W0 made on the device from the seed in the engine's sharding,
            the service started (its solve, and with learning its fit,
-           compiled and run once), the first samples drawn.
-  window   opens when the first sample is due; load is offered for
+           compiled and run once), the first requests drawn.
+  window   opens when the first request is due; load is offered for
            --seconds.  A rate's window closes at the first completion of
            its unit (a coded micro-batch, a fit step) at or after
            --seconds; a latency is taken over every sample due in it.
-  check    after the window: the device's peak memory is read, the
-           program's state freed, and the plain reference
-           (bench/reference.py) follows the program's first fit steps
-           and re-solves a seeded sample of the served samples.
+  check    after the window: the device's peak memory is read, the kind
+           picks the served samples it checks, the program's state is
+           freed, and the kind's plain reference follows the program's
+           first fit steps and compares.  The harness adds its own counts:
+           an unclosed window, samples unchecked, failed, unfollowed or
+           foreign fits.
 
 Prints the device, then as the last line of standard output one JSON
 object: correct, attempted, failed, metrics, device (and with --trace 1 a
@@ -39,15 +49,19 @@ import time
 T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
+import inspect  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
-from typing import Callable, Optional  # noqa: E402
+from types import ModuleType  # noqa: E402
+from typing import Callable, NamedTuple, Optional  # noqa: E402
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -57,7 +71,6 @@ sys.path.insert(0, str(ROOT / "src"))
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 import reference  # noqa: E402
 import trace as trace_mod  # noqa: E402
@@ -65,7 +78,7 @@ import traffic  # noqa: E402
 import work  # noqa: E402
 from probe import EngineProbe  # noqa: E402
 
-CHECK_ROWS = 64  # served samples re-solved by the reference
+DEFAULT_KIND = "coding"
 DIGEST_ATOMS = 512  # atoms whose change over the first fits is compared
 FOLLOW_FITS = 3  # fit steps the reference follows
 CLOSE_TIMEOUT_S = 120.0  # past --seconds, a window that has not closed fails
@@ -93,6 +106,7 @@ def cell_parts(name: str, man: dict, bench: pathlib.Path = HERE):
     cell = cells[name]
     conf = {c["name"]: c for c in man["configs"]}[cell["config"]]
     cfg = json.loads((bench.parent / conf["file"]).read_text())
+    kind_of(cfg, bench)  # a kind that is missing, or does not follow the task, fails here
     return cell, cfg, traffic.load(cell["traffic"], bench)
 
 
@@ -103,36 +117,58 @@ def metrics_of(cell: dict, man: dict, traced: bool) -> list:
     return [m for m in group if cell["name"] in m.get("workloads", [cell["name"]])]
 
 
-def reader(name: str, bench: pathlib.Path = HERE) -> Callable:
-    """`read(ctx)` of bench/metrics/<name>.py."""
-    path = bench / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+@functools.lru_cache(maxsize=None)
+def _module(path: pathlib.Path, name: str) -> ModuleType:
+    """The Python file at `path`, loaded once per process."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, bench: pathlib.Path = HERE) -> Callable:
+    """`read(ctx)` of bench/metrics/<name>.py."""
+    return _module(bench / "metrics" / f"{name}.py", f"bench_metric_{name}").read
+
+
+def kind_of(cfg: dict, bench: pathlib.Path = HERE) -> ModuleType:
+    """The configuration's kind, bench/kinds/<kind>.py.  Its `FOLLOWS` maps
+    "task" and each `DistConfig` field that its reference follows to the
+    values it follows (None: any); a configuration that sets another task,
+    another value, or any other `DistConfig` field away from its default is
+    refused."""
+    from repro.core.distributed import DistConfig
+
+    name = cfg.get("kind", DEFAULT_KIND)
+    kind = _module(bench / "kinds" / f"{name}.py", f"bench_kind_{name}")
+    fields = {f.name: f for f in dataclasses.fields(DistConfig)}
+    for key, value in {"task": cfg["task"], **options(cfg, fields)}.items():
+        if key in kind.FOLLOWS:
+            allowed = kind.FOLLOWS[key]
+            if allowed is None or value in allowed:
+                continue
+            why = f"{key} in {sorted(allowed)}"
+        elif key == "task":
+            why = "no task"
+        else:
+            field = fields[key]
+            default = (field.default if field.default is not dataclasses.MISSING
+                       else field.default_factory())
+            if value == default:
+                continue
+            why = f"{key} only at its default {default!r}"
+        raise ValueError(f"configuration {cfg['name']}: kind {name!r} follows {why}, "
+                         f"not {value!r}")
+    return kind
 
 
 # -- the system under test --------------------------------------------------
 
 
-def _key(seed: int, tag: int) -> jax.Array:
-    word = np.random.SeedSequence([seed % 2**63, tag]).generate_state(1)[0]
-    return jax.random.PRNGKey(int(word))
-
-
-def w0_maker(cfg: dict, mesh, seed: int) -> Callable:
-    """W0 on the device in one jitted call from the seed: unit-norm Gaussian
-    atoms, float32, in the engine's W sharding (atoms over `model`)."""
-    m, k = cfg["m"], cfg["atoms"]
-    sharding = NamedSharding(mesh, P(None, "model"))
-
-    @jax.jit
-    def draw(key):
-        W = jax.random.normal(key, (m, k), jnp.float32)
-        W = W / jnp.maximum(jnp.linalg.norm(W, axis=0, keepdims=True), 1e-12)
-        return jax.lax.with_sharding_constraint(W, sharding)
-
-    return lambda: draw(_key(seed, 4))
+def options(cfg: dict, names) -> dict:
+    """The configuration's values of the keys in `names`, a list as a tuple
+    (JSON has none)."""
+    return {k: tuple(v) if isinstance(v, list) else v for k, v in cfg.items() if k in names}
 
 
 def digest_atoms(cfg: dict, seed: int):
@@ -150,7 +186,8 @@ def digest_atoms(cfg: dict, seed: int):
     return cols, digest
 
 
-def build(cfg: dict, devices, seed: int):
+def build(cfg: dict, devices):
+    """(mesh, coder): the engine as the configuration states it."""
     from repro.core.conjugates import make_task
     from repro.core.distributed import DistConfig, DistributedSparseCoder
     from repro.runtime import dist
@@ -158,42 +195,85 @@ def build(cfg: dict, devices, seed: int):
     data, model = cfg["mesh"]
     mesh = dist.make_mesh((data, model), (dist.DATA_AXIS, dist.MODEL_AXIS),
                           devices=np.asarray(devices))
-    res, reg = make_task(cfg["task"], gamma=cfg["gamma"], delta=cfg["delta"])
-    coder = DistributedSparseCoder(mesh, res, reg,
-                                   DistConfig(mode=cfg["mode"], iters=cfg["iters"]))
-    return mesh, coder
+    task_args = set(inspect.signature(make_task).parameters) - {"name"}
+    res, reg = make_task(cfg["task"], **options(cfg, task_args))
+    engine = DistConfig(**options(cfg, {f.name for f in dataclasses.fields(DistConfig)}))
+    return mesh, DistributedSparseCoder(mesh, res, reg, engine)
+
+
+def service_config(cfg: dict, learn: bool):
+    """The service's ServiceConfig: the configuration's keys that name its
+    fields, `max_wait_ms` in seconds, and learning as the mix says."""
+    from repro.runtime.service import ServiceConfig
+
+    opts = options(cfg, {f.name for f in dataclasses.fields(ServiceConfig)})
+    if "max_wait_ms" in cfg:
+        opts["max_wait_s"] = cfg["max_wait_ms"] / 1e3
+    opts["learn"] = learn
+    return ServiceConfig(**opts)
 
 
 # -- one run ------------------------------------------------------------------
 
 
-class Client(threading.Thread):
-    """Submits the mix's samples; records due, submit and done times
-    (seconds from the window's open), the micro-batch each sample was
-    coded in, and each sample's result."""
+class Served(NamedTuple):
+    """A sample the service answered, as a kind's `pick` sees it: its
+    request's index, its place in that request, the sample, the dictionary
+    version it was coded against, and its future."""
+    request: int
+    pos: int
+    x: np.ndarray
+    version: int
+    future: object
 
-    def __init__(self, svc, probe, stream, mix, cfg, seed, seconds, prefill):
+
+class Client(threading.Thread):
+    """Submits the kind's requests as the mix says; records per sample its
+    request, due, submit and done times (seconds from the window's open),
+    the micro-batch it was coded in, and its future.
+
+    Each sample's callback is attached while the probe's gate is held, so
+    no solve that holds the sample starts before its callback is in place:
+    the callback runs in the batcher as the future resolves, and reads the
+    done time and the solve it came from then.  A kind without `submit` is
+    submitted sample by sample, the gate held for one sample at a time, and
+    a solve that waits goes before the next sample; a kind's own `submit`
+    holds it for the whole request."""
+
+    def __init__(self, svc, probe, kind, requests, mix, cfg, seed, seconds):
         super().__init__(name="bench-client", daemon=True)
-        self.svc, self.probe, self.stream = svc, probe, stream
+        self.svc, self.probe, self.kind, self.requests = svc, probe, kind, requests
         self.stop = threading.Event()
         self.lock = threading.Lock()
-        self.x, self.due, self.sub, self.done, self.batch, self.futs = [], [], [], [], [], []
+        self.x, self.req, self.due, self.sub, self.done, self.batch, self.futs = (
+            [], [], [], [], [], [], [])
+        self.first = []  # per request, the index of its first sample
+        self.n_submitted = 0  # requests whose every future is attached
         self.t_open = None
         if mix["arrivals"] == "poisson":
             self.schedule = traffic.due_times(mix, seed, seconds)
             self.cap = None
+            prefill = 8
         else:
             self.schedule = None
-            self.cap = threading.Semaphore(int(mix["outstanding_batches"]) * cfg["micro_batch"])
-        self.ready = [stream.next() for _ in range(prefill)]
+            self.cap_samples = prefill = int(mix["outstanding_batches"]) * cfg["micro_batch"]
+            self.cap = threading.Semaphore(self.cap_samples)
+        self.ready = []  # requests drawn ahead of the window: `prefill` samples or more
+        while sum(len(r) for r in self.ready) < prefill:
+            self.ready.append(next(requests))
 
-    def _next_x(self):
-        return self.ready.pop(0) if self.ready else self.stream.next()
+    def _next_request(self) -> np.ndarray:
+        request = self.ready.pop(0) if self.ready else next(self.requests)
+        if self.cap is not None and len(request) > self.cap_samples:
+            raise ValueError(f"a request of {len(request)} samples exceeds the backlog's "
+                             f"cap of {self.cap_samples}")
+        return request
 
     def _on_done(self, i):
         def cb(fut):
             t = time.perf_counter() - self.t_open
-            bid = self.probe.solves_done - 1
+            failed = fut.cancelled() or fut.exception() is not None
+            bid = None if failed else self.probe.solves_done - 1
             with self.lock:
                 self.done[i] = t
                 self.batch[i] = bid
@@ -201,38 +281,57 @@ class Client(threading.Thread):
                 self.cap.release()
         return cb
 
-    def _submit(self, i, x, due):
-        with self.lock:
-            self.x.append(x)
-            self.due.append(due)
-            self.sub.append(None)
-            self.done.append(None)
-            self.batch.append(None)
-        fut = self.svc.submit(x)
-        self.sub[i] = time.perf_counter() - self.t_open
-        self.futs.append(fut)
+    def _attach(self, i, fut, t_sub):
+        self.sub[i] = t_sub
+        self.futs[i] = fut
         fut.add_done_callback(self._on_done(i))
+
+    def _submit(self, request, due):
+        n = len(request)
+        with self.lock:
+            i0 = len(self.x)
+            self.first.append(i0)
+            self.x.extend(request)
+            self.req.extend([len(self.first) - 1] * n)
+            for column, value in ((self.due, due), (self.sub, None), (self.done, None),
+                                  (self.batch, None), (self.futs, None)):
+                column.extend([value] * n)
+        submit = getattr(self.kind, "submit", None)
+        if submit is None:
+            for j, x in enumerate(request):
+                with self.probe.gate.held():
+                    fut = self.svc.submit(x)
+                    self._attach(i0 + j, fut, time.perf_counter() - self.t_open)
+        else:
+            with self.probe.gate.held():
+                futs = submit(self.svc, request)
+                t_sub = time.perf_counter() - self.t_open
+                if len(futs) != n:
+                    raise RuntimeError(f"the kind gave {len(futs)} futures for {n} samples")
+                for j, fut in enumerate(futs):
+                    self._attach(i0 + j, fut, t_sub)
+        with self.lock:
+            self.n_submitted += 1
 
     def run(self):
         try:
             if self.schedule is not None:
-                for i, due in enumerate(self.schedule):
-                    x = self._next_x()
+                for due in self.schedule:
+                    request = self._next_request()
                     wait = self.t_open + due - time.perf_counter()
                     if wait > 0 and self.stop.wait(wait):
                         return
                     if self.stop.is_set():
                         return
-                    self._submit(i, x, float(due))
+                    self._submit(request, float(due))
             else:
-                i = 0
                 while not self.stop.is_set():
-                    x = self._next_x()
-                    while not self.cap.acquire(timeout=0.05):
-                        if self.stop.is_set():
-                            return
-                    self._submit(i, x, 0.0)
-                    i += 1
+                    request = self._next_request()
+                    for _ in range(len(request)):
+                        while not self.cap.acquire(timeout=0.05):
+                            if self.stop.is_set():
+                                return
+                    self._submit(request, 0.0)
         except RuntimeError:
             if not self.stop.is_set():  # the service stopped under the client
                 raise
@@ -249,29 +348,27 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
              bench: pathlib.Path = HERE, ctx_out: Optional[dict] = None) -> dict:
     """One run; returns the result object (without printing it).  `wrap`
     replaces the engine under the service (tests plant faults with it);
-    `ctx_out`, when given, receives what the metric readers read."""
-    from repro.runtime.service import DictionaryService, ServiceConfig
+    `ctx_out`, when given, receives what the metric readers read, and
+    `samples` (per sample its row, solve index and done time) and `solves`
+    (per solve its times and input rows, read to the host)."""
+    from repro.runtime.service import DictionaryService
 
+    kind = kind_of(cfg, bench)
     learn = bool(mix["learn"])
-    mesh, coder = build(cfg, devices, seed)
+    mesh, coder = build(cfg, devices)
     if wrap is not None:
         coder = wrap(coder)
-    W0_fn = w0_maker(cfg, mesh, seed)
-    rng = np.random.default_rng(np.random.SeedSequence([seed % 2**63, 5]))
+    W0_fn = kind.w0(cfg, mesh, seed)
+    rng = np.random.default_rng(traffic.seed_words(seed, 5))
     cols, digest = digest_atoms(cfg, seed)
     probe = EngineProbe(coder, digest, FOLLOW_FITS if learn else 0)
     W0 = W0_fn()
     if learn:
         jax.block_until_ready(digest(W0))  # compiled here, not in the window
-    svc = DictionaryService(probe, W0, ServiceConfig(
-        micro_batch=cfg["micro_batch"], max_wait_s=cfg["max_wait_ms"] / 1e3,
-        learn=learn, mu_w=cfg["mu_w"]))
+    svc = DictionaryService(probe, W0, service_config(cfg, learn))
     del W0
     svc.start()
-    stream = traffic.Stream(cfg["m"], cfg["atoms"], mix, seed)
-    prefill = (int(mix["outstanding_batches"]) * cfg["micro_batch"]
-               if mix["arrivals"] == "backlog" else 8)
-    client = Client(svc, probe, stream, mix, cfg, seed, seconds, prefill)
+    client = Client(svc, probe, kind, kind.requests(cfg, mix, seed), mix, cfg, seed, seconds)
     if traced:
         shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
@@ -297,8 +394,7 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
         now = time.perf_counter() - t_open
         if mix["arrivals"] == "poisson":
             with client.lock:
-                n_due = len(client.schedule)
-                all_done = (len(client.done) == n_due
+                all_done = (client.n_submitted == len(client.schedule)
                             and all(d is not None for d in client.done))
             closed = now >= seconds and all_done
         else:
@@ -352,36 +448,31 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
         done=[done[i] if client.futs[i].exception() is None else None for i in in_window],
     )
     if ctx_out is not None:
-        ctx_out.update(ctx)
+        ctx_out.update(ctx, samples=dict(x=list(client.x), batch=bid, done=done),
+                       solves=[dict(s, x=np.asarray(s["x"])) for s in solves])
 
     # -- the check -------------------------------------------------------------
     n_follow = min(FOLLOW_FITS, len(fits)) if learn else 0
     version = [solves[b]["version"] if b is not None and b < len(solves) else -1 for b in bid]
-    ok_rows = [i for i in range(len(bid)) if done[i] is not None and version[i] >= 0
-               and version[i] <= n_follow and client.futs[i].exception() is None]
-    pick = sorted(rng.choice(ok_rows, min(CHECK_ROWS, len(ok_rows)), replace=False)) if ok_rows else []
-    sampled = {}
-    for i in pick:
-        nu, y = client.futs[i].result()
-        s = sampled.setdefault(version[i], {"x": [], "nu": [], "y": []})
-        s["x"].append(client.x[i])
-        s["nu"].append(np.asarray(nu))
-        s["y"].append(np.asarray(y))
-    sampled = {v: {k: np.stack(a) for k, a in s.items()} for v, s in sampled.items()}
+    served = [Served(client.req[i], i - client.first[client.req[i]], client.x[i], version[i],
+                     client.futs[i])
+              for i in range(len(bid)) if done[i] is not None and version[i] >= 0
+              and version[i] <= n_follow and client.futs[i].exception() is None]
+    picked, unchecked = kind.pick(cfg, served, rng)
     fit_inputs = [f["x"] for f in fits[:n_follow]]
     foreign = reference.foreign_rows(fit_inputs, {hash(x.tobytes()) for x in client.x})
     digests = [None] + probe.digests[:n_follow]
     fit_failures = stats["fit_failures"]
-    del svc, probe, coder, client, solves, fits
+    del svc, probe, coder, client, solves, fits, served
     gc.collect()
-    numbers = reference.compare(cfg, W0_fn, sampled, fit_inputs, cols, digests, CHECK_ROWS)
+    numbers = kind.check(cfg, seed, W0_fn, picked, fit_inputs, cols, digests)
     checks = reference.judge(numbers, cfg["limits"])
     # each count below must be 0: a window that never closed, samples the
     # check could not draw, fit steps that failed, fit steps the reference
     # had none of to follow while learning was on, and rows of the followed
     # fits that are not distinct samples the client sent
     checks["unclosed_window"] = {"value": int(not closed), "limit": 0}
-    checks["samples_unchecked"] = {"value": CHECK_ROWS - len(pick), "limit": 0}
+    checks["samples_unchecked"] = {"value": int(unchecked), "limit": 0}
     checks["fit_failures"] = {"value": int(fit_failures), "limit": 0}
     checks["fits_unfollowed"] = {"value": int(learn and n_follow == 0), "limit": 0}
     checks["fit_rows_foreign"] = {"value": int(foreign), "limit": 0}

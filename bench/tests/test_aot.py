@@ -1,6 +1,7 @@
 """Ahead-of-time compiles of each cell's solve and fit programs at the real
 widths, for a described TPU v5e: one chip, and a v5e:2x2 for the
-four-agent cell.  Nothing runs; the TPU compiler refuses here what the chip
+four-agent cell.  The fit is the atom update alone, from the duals a solve
+returned: `_fit(W, nu, y, mu_w)`.  Nothing runs; the TPU compiler refuses here what the chip
 would refuse (a program over its 16 GB, a sharding it cannot partition).
 
 The topology is described inside a fixture, never while a module is
@@ -60,17 +61,27 @@ def test_cell_programs_compile_for_v5e(topo, config, program):
     x = jax.ShapeDtypeStruct((cfg["micro_batch"], cfg["m"]), jnp.float32,
                              sharding=NamedSharding(mesh, P(dist.DATA_AXIS, None)))
     scalar = NamedSharding(mesh, P())
-    t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar)
     if program == "solve":
+        t0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar)
         lowered = coder._solve.lower(W, x, t0)
     else:
+        # the fit takes a solve's duals, sharded as the solve returns them
+        b = cfg["micro_batch"]
+        nu = jax.ShapeDtypeStruct((b, cfg["m"]), jnp.float32,
+                                  sharding=NamedSharding(mesh, P(dist.DATA_AXIS, None)))
+        y = jax.ShapeDtypeStruct((b, cfg["atoms"]), jnp.float32,
+                                 sharding=NamedSharding(mesh, P(dist.DATA_AXIS, dist.MODEL_AXIS)))
         mu = jax.ShapeDtypeStruct((), jnp.float32, sharding=scalar)
-        lowered = coder._fit.lower(W, x, mu, t0)
+        lowered = coder._fit.lower(W, nu, y, mu)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     per_device = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                   + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert per_device < HBM_BYTES
     text = compiled.as_text()
-    # the agents' duals meet in an all-reduce only when atoms are sharded
-    assert ("all-reduce" in text) == (model > 1)
+    if program == "solve":
+        # the agents' duals meet in an all-reduce only when atoms are sharded
+        assert ("all-reduce" in text) == (model > 1)
+    else:
+        # the atom update reduces over the data axes only: none here
+        assert "all-reduce" not in text

@@ -3,16 +3,24 @@
 `test_reductions_by_hand` checks each reduction on a trace small enough to
 count by hand.  `test_recorded_trace` reads a trace recorded on the chip
 (tests/record_fixture.py): two solves and one fit of the engine, with the
-harness's spans."""
+harness's spans.  `test_service_spans_only_name_the_idle_gaps` reads one
+recorded through the service, whose own spans name the idle gaps and move
+no reading."""
 
 import pathlib
 
 import pytest
 
+import run
 import trace
 
 FIXTURES = pathlib.Path(__file__).resolve().parent / "fixtures"
+SERVICE_TRACE = FIXTURES / "trace_1chip_service.xplane.pb"
 MS = 1_000_000
+# the per-layer metrics read from a trace
+TRACE_READERS = ("engine.solve_ms.backlog", "engine.solve_ms.rate", "engine.fit_ms",
+                 "engine.solve_roofline", "collectives.exposed_ms", "device.idle.backlog",
+                 "device.idle.rate")
 
 
 def _hand_trace():
@@ -58,9 +66,9 @@ def test_interval_arithmetic():
     assert trace.measure([(0, 2), (1, 3)]) == 3
 
 
-@pytest.mark.skipif(not list(FIXTURES.glob("*.xplane.pb")), reason="no recorded trace")
+@pytest.mark.skipif(not list(FIXTURES.glob("trace_*chip.xplane.pb")), reason="no recorded trace")
 def test_recorded_trace():
-    for pb in sorted(FIXTURES.glob("*.xplane.pb")):
+    for pb in sorted(FIXTURES.glob("trace_*chip.xplane.pb")):
         tr = trace.load(str(pb.parent), pattern=pb.name)
         win = trace.window(tr)
         assert win is not None
@@ -72,3 +80,31 @@ def test_recorded_trace():
         if len(tr["devices"]) > 1:
             assert any(trace.COLLECTIVE.search(n) for v in tr["devices"].values()
                        for n, _, _ in v["ops"])
+
+
+def test_service_spans_only_name_the_idle_gaps():
+    """The service's and engine's spans kept beside the harness's: every
+    per-layer reading, the busy time and the top ops are what they were
+    when only `bench.*` spans were kept, and the gaps are the same gaps;
+    only their names change."""
+    new = trace.load(str(FIXTURES), pattern=SERVICE_TRACE.name)
+    old = dict(new, host=[h for h in new["host"] if h[0].startswith("bench.")])
+    assert {"service.exec.solve", "engine.solve", "engine.fit"} <= {n for n, _, _ in new["host"]}
+    win = trace.window(new)
+    assert win is not None and win == trace.window(old)
+    # the recorder's sizes: M=512, 1024 atoms per chip, 20 iterations, 64-sample batches
+    cfg = {"m": 512, "atoms": 1024 * len(new["devices"]), "mesh": [1, len(new["devices"])],
+           "iters": 20, "micro_batch": 64, "dtype": "float32"}
+
+    def ctx(tr):
+        return {"trace": tr, "trace_window": win, "cfg": cfg, "kind": "TPU v5 lite"}
+
+    readings = {name: run.reader(name)(ctx(new)) for name in TRACE_READERS}
+    assert readings == {name: run.reader(name)(ctx(old)) for name in TRACE_READERS}
+    assert readings["engine.solve_ms.backlog"] > 0 and readings["engine.fit_ms"] > 0
+    assert trace.busy(new, win) == trace.busy(old, win)
+    assert trace.top_ops(new, win) == trace.top_ops(old, win)
+    gaps, gaps_before = trace.idle_gaps(new, win), trace.idle_gaps(old, win)
+    assert [g[1] for g in gaps] == [g[1] for g in gaps_before]
+    assert any(("service." in g[0] or "engine." in g[0]) and g[0] != b[0]
+               for g, b in zip(gaps, gaps_before))

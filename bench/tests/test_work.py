@@ -10,7 +10,9 @@ CFG = {"m": 8192, "atoms": 65536, "mesh": [1, 1], "iters": 100, "dtype": "float3
 def test_solve_and_fit_operations():
     # 100 iterations x (W^T nu and y W^T) x 2 B M K
     assert work.solve_flops(CFG, 256) == 100 * 2 * (2 * 256 * 8192 * 65536)
-    assert work.fit_flops(CFG, 256) == work.solve_flops(CFG, 256) + 2 * 256 * 8192 * 65536
+    # the fit is the atom update nu^T y alone: it takes the solve's duals
+    assert work.fit_flops(CFG, 256) == 2 * 256 * 8192 * 65536
+    assert work.fit_flops(dict(CFG, atoms=262144, mesh=[1, 4]), 64) == 2 * 64 * 8192 * 65536
 
 
 def test_atoms_split_over_agents():

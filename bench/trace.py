@@ -5,7 +5,9 @@ A device plane is one named `/device:TPU:<n>` (one per chip); on it the
 line `XLA Modules` holds one event per program run (named after the jitted
 function, e.g. `jit__solve_body(...)`) and `XLA Ops` one per operation.
 Host spans written with `jax.profiler.TraceAnnotation` sit on the host
-plane's thread lines.
+plane's thread lines: the harness's own (`bench.*`) and the service's and
+engine's (`service.*`, `engine.*`, runtime/tracing.py), which name what
+the host was doing while the device idled.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ Interval = Tuple[int, int]
 COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all|"
                         r"psum|ppermute")
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+HOST_SPANS = ("bench.", "service.", "engine.")  # host events kept, by name prefix
 
 
 def load(log_dir: str, pattern: str = "*.xplane.pb") -> dict:
@@ -46,7 +49,7 @@ def load(log_dir: str, pattern: str = "*.xplane.pb") -> dict:
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 out["host"].extend((e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
-                                   for e in line.events if e.name.startswith("bench."))
+                                   for e in line.events if e.name.startswith(HOST_SPANS))
     return out
 
 
@@ -160,8 +163,9 @@ def clip_named(events, win: Interval):
 
 def idle_gaps(tr: dict, win: Interval, n: int = 10) -> List[Tuple[str, float]]:
     """The n longest gaps on the first device in which no operation ran,
-    each named by the harness span the host was in at the gap's middle
-    ("service host work" when no engine call was in flight)."""
+    each named by the host spans in flight at the gap's middle, the
+    service's and the engine's beside the harness's ("service host work"
+    when none was)."""
     devs = sorted(d for d, v in tr["devices"].items() if v["ops"])
     if not devs:
         return []

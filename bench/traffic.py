@@ -1,20 +1,17 @@
 """The one traffic generator.  A mix is a data file `traffic/<name>.json`
 that this module reads; nothing here knows a mix by name.
 
-Samples follow the planted sparse-code model of the program's synthetic
-stream (a copy of its recipe, not an import): x = sum_s c_s a_{j_s} + noise,
-with `sparsity` planted atoms a_j drawn from a K-atom dictionary that is
-generated atom by atom from (seed, j), coefficients uniform in [0.5, 1.5]
-with random signs, and Gaussian noise.  Sample i is the i-th draw of one
-seeded stream, so a seed fixes every sample, and every seed gives samples
-of the same size and the same count of planted atoms.
+What a request holds comes from the configuration's kind
+(`bench/kinds/<kind>.py`, which reads the rest of the mix: sizes, noise);
+a request is n >= 1 samples due at one moment.  This module says when:
 
-Arrivals:
-  * "poisson": an open loop at `rate_per_s`; sample i is due at the i-th
-    arrival of a seeded Poisson process, whether or not earlier ones are done.
-  * "backlog": offered load above capacity; every sample is due at the
-    window's open and the client keeps `outstanding_batches` micro-batches
-    submitted and not yet coded, so the service's queue never runs dry.
+  * "poisson": an open loop at `rate_per_s` requests per second; request i
+    is due at the i-th arrival of a seeded Poisson process, whether or not
+    earlier ones are done.
+  * "backlog": offered load above capacity; every request is due at the
+    window's open and the client keeps `outstanding_batches` micro-batches'
+    worth of samples submitted and not yet coded, so the service's queue
+    never runs dry.
 """
 
 from __future__ import annotations
@@ -41,43 +38,15 @@ def load(name: str, root: pathlib.Path = HERE) -> dict:
     return mix
 
 
-def _seed_words(seed: int, *tag: int) -> np.random.SeedSequence:
+def seed_words(seed: int, *tag: int) -> np.random.SeedSequence:
+    """The seed sequence of one stream drawn from the run's seed, by tag."""
     return np.random.SeedSequence([seed % 2**63, *tag])
-
-
-class Stream:
-    """Samples of one seeded planted stream, drawn one at a time in order."""
-
-    def __init__(self, m: int, atoms: int, mix: dict, seed: int):
-        self.m, self.atoms = m, atoms
-        self.sparsity = int(mix["sparsity"])
-        self.noise = float(mix["noise"])
-        self.seed = seed % 2**63
-        self._rng = np.random.default_rng(_seed_words(seed, 1))
-
-    def atom(self, j: int) -> np.ndarray:
-        """Planted atom j, unit norm, from its own stream (seed, j)."""
-        a = np.random.default_rng(_seed_words(self.seed, 2, j)).standard_normal(
-            self.m, dtype=np.float32)
-        return a / np.linalg.norm(a)
-
-    def next(self) -> np.ndarray:
-        rng = self._rng
-        idx = set()
-        while len(idx) < self.sparsity:
-            idx.add(int(rng.integers(self.atoms)))
-        sign = rng.choice(np.array([-1.0, 1.0], np.float32), self.sparsity)
-        coef = rng.uniform(0.5, 1.5, self.sparsity).astype(np.float32) * sign
-        x = self.noise * rng.standard_normal(self.m, dtype=np.float32)
-        for c, j in zip(coef, sorted(idx)):
-            x += c * self.atom(j)
-        return x.astype(np.float32)
 
 
 def due_times(mix: dict, seed: int, seconds: float) -> np.ndarray:
     """Due times (s from the window's open) of the poisson arrivals in
     [0, seconds)."""
-    rng = np.random.default_rng(_seed_words(seed, 3))
+    rng = np.random.default_rng(seed_words(seed, 3))
     rate = float(mix["rate_per_s"])
     n = int(rate * seconds * 1.5) + 64
     t = np.cumsum(rng.exponential(1.0 / rate, n))

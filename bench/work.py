@@ -3,8 +3,9 @@ shapes, and the least time a chip could take for them.
 
 Counts are per chip: a solve of a micro-batch of B samples runs `iters`
 iterations, each two products of B x M by M x K_loc (W^T nu and y W^T),
-so 4 B M K_loc operations; a fit is a solve plus the gradient nu^T y,
-2 B M K_loc more.  The bytes are the least any implementation must move:
+so 4 B M K_loc operations; a fit (`jit__fit_body`) is the atom update
+alone, the gradient nu^T y from the duals its batch's solve returned,
+2 B M K_loc operations.  The bytes are the least any implementation must move:
 W (M x K_loc, larger than on-chip memory) read once per iteration, plus
 the batch's nu and y written once.  The step-size estimate (a power
 iteration over W in every call) is not counted: no implementation needs
@@ -34,8 +35,9 @@ def solve_flops(cfg: dict, batch: int) -> float:
 
 
 def fit_flops(cfg: dict, batch: int) -> float:
-    """Operations of one fit step of `batch` samples, on one chip."""
-    return solve_flops(cfg, batch) + 2.0 * batch * cfg["m"] * atoms_per_chip(cfg)
+    """Operations of one fit step of `batch` samples, on one chip: the atom
+    update from the duals of the batch's solve, which the solve counts."""
+    return 2.0 * batch * cfg["m"] * atoms_per_chip(cfg)
 
 
 def solve_bytes(cfg: dict, batch: int) -> float:

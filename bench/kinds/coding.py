@@ -14,15 +14,31 @@ there is none) and calls:
                              refused before a run
   w0(cfg, mesh, seed)        a function that makes W0 on the device, in the
                              engine's sharding, the same for the same seed
-  requests(cfg, mix, seed)   an endless iterator of requests: (n, M) float32
-                             arrays of n >= 1 samples due at one moment
+  requests(cfg, mix, seed)   an endless iterator of requests, each due at
+                             one moment
+  samples(cfg, request)      (optional) the request's n >= 1 samples, the
+                             (n, M) float32 rows the engine codes; without
+                             it a request is its own samples.  The harness
+                             takes them where it draws the request, ahead of
+                             its due time (prefill, the backlog's cap),
+                             and again after the window (`fit_rows_foreign`)
   submit(svc, request)       (optional) hands one request to the service and
-                             returns one future per sample, in the request's
-                             order; without it the harness submits the
-                             request's samples one by one (`svc.submit`)
-  pick(cfg, served, rng)     after the window: which served samples the check
-                             compares, read to the host, and how many it
-                             wanted but could not have
+                             returns either one future per sample, in the
+                             order of its samples, or one
+                             `concurrent.futures.Future` for the whole
+                             request, which the harness times as one: one
+                             callback, its due, submit and done times and
+                             micro-batch given to each of its samples.
+                             It is called once the service's queue has room
+                             for the request's samples, under the probe's
+                             gate.  Without it the harness submits the
+                             samples one by one (`svc.submit`)
+  pick(cfg, served, rng)     after the window: which of the served `Served`
+                             entries (bench/run.py) the check compares, read
+                             to the host, and how many samples it wanted but
+                             could not have.  An entry is a sample, or where
+                             `submit` gave one future a whole request (`pos`
+                             0, `x` the request as sent)
   check(cfg, seed, W0_fn, picked, fits, cols, digests)
                              the numbers compared against cfg["limits"]
   control(cfg, mix, seed, W0_fn, cols, follow, matmul)

@@ -3,16 +3,21 @@ benchmark's side: the service is handed this wrapper in place of its
 `DistributedSparseCoder` and sees the same object in every other respect.
 
 Each armed call is recorded with its start and end on the host clock
-(perf_counter), the dictionary version it ran against, and its input
-batch; the end is taken after the result is ready on the device, which the
-service waits for next in any case.  Spans also go into the profiler's
+(perf_counter) and the dictionary version it ran against; the end is taken
+after the result is ready on the device, which the service waits for next
+in any case.  A fit keeps its input batch, which the reference follows and
+`learned_per_s` counts the real rows of after the window; a solve keeps
+its input only where the caller asks for it (`keep_solve_inputs`, for the
+tests), since a window's solve inputs would otherwise stay on the device
+to its close and count in its peak memory.  Spans also go into the profiler's
 trace as `bench.solve` / `bench.fit`, so a traced run can say what the host
 was doing while the device idled.
 
-`gate` is held by the benchmark's client while it submits a sample and
-attaches the sample's done callback.  A solve passes through it before it
-starts, so a solve that holds a sample waits until that sample's callback
-is in place, and the callback runs in the batcher as the sample resolves.
+`gate` is held by the benchmark's client while it submits a sample, or a
+request that its kind answers with one future, and attaches the done
+callback.  A solve passes through it before it starts, so a solve that
+holds a sample waits until that sample's callback is in place, and the
+callback runs in the batcher as the future resolves.
 """
 
 from __future__ import annotations
@@ -63,16 +68,18 @@ class Gate:
 
 
 class EngineProbe:
-    def __init__(self, coder, digest: Optional[Callable] = None, digest_fits: int = 0):
+    def __init__(self, coder, digest: Optional[Callable] = None, digest_fits: int = 0,
+                 keep_solve_inputs: bool = False):
         self._inner = coder
         self._digest = digest
         self._digest_fits = digest_fits
+        self._keep_solve_inputs = keep_solve_inputs
         self._lock = threading.Lock()
         self.gate = Gate()
         self._versions = {}  # id(W) -> (weakref to W, version)
         self._last_snapshot = None
         self.armed = False
-        self.solves = []  # dicts: t0, t1, version, x (device array)
+        self.solves = []  # dicts: t0, t1, version (and x, the device batch, if kept)
         self.fits = []  # dicts: t0, t1, x (device array)
         self.digests = []  # host W[:, cols] after fit 1, 2, ...
 
@@ -111,7 +118,10 @@ class EngineProbe:
             out = jax.block_until_ready(self._inner.solve(W, x, t0))
         t_end = time.perf_counter()
         with self._lock:
-            self.solves.append(dict(t0=t_start, t1=t_end, version=self._version(W), x=x))
+            record = dict(t0=t_start, t1=t_end, version=self._version(W))
+            if self._keep_solve_inputs:
+                record["x"] = x
+            self.solves.append(record)
         return out
 
     def fit_batch(self, W, x, mu_w: float, t0: int = 0):

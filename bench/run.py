@@ -7,8 +7,9 @@ A cell (an entry of `workloads` in BENCHMARK.json) names a configuration,
 `bench/configs/<config>.json`, and a traffic mix, `bench/traffic/<mix>.json`;
 each metric is read by `bench/metrics/<metric>.py`.  The configuration's
 `kind` (default "coding") names `bench/kinds/<kind>.py`, which brings what
-is the deployment's own: W0, the requests, how a request is submitted, and
-the reference and numbers that decide `correct` (see kinds/coding.py).
+is the deployment's own: W0, the requests and their samples, how a request
+is submitted, and the reference and numbers that decide `correct` (see
+kinds/coding.py).
 Every other configuration key that names a field of the engine's
 `DistConfig`, the service's `ServiceConfig` or a parameter of `make_task`
 is passed to it as it stands (`max_wait_ms` as `max_wait_s`).  Nothing
@@ -17,8 +18,10 @@ here knows a cell, a configuration, a kind, a mix or a metric by name.
 The run drives the program's own serving path: `DictionaryService`
 (runtime/service.py) over `DistributedSparseCoder` (core/distributed.py),
 fed by a client thread that submits the kind's requests as the mix says.
-A request is n >= 1 samples due at one moment; every time and count below
-is per sample.
+A request is due at one moment and holds n >= 1 samples, the rows the
+engine codes; every time and count below is per sample (a request that the
+kind answers with one future gives each of its samples the request's
+times).
 
   set-up   process start to the window's open: JAX and the chip, the mesh,
            W0 made on the device from the seed in the engine's sharding,
@@ -60,6 +63,7 @@ import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
+from concurrent.futures import Future  # noqa: E402
 from types import ModuleType  # noqa: E402
 from typing import Callable, NamedTuple, Optional  # noqa: E402
 
@@ -217,9 +221,11 @@ def service_config(cfg: dict, learn: bool):
 
 
 class Served(NamedTuple):
-    """A sample the service answered, as a kind's `pick` sees it: its
-    request's index, its place in that request, the sample, the dictionary
-    version it was coded against, and its future."""
+    """What the service answered, as a kind's `pick` sees it: its request's
+    index, its place in that request, the sample, the dictionary version it
+    was coded against, and its future.  A request that the kind answers with
+    one future is one entry: place 0, `x` the request as sent, and the
+    version of the last micro-batch that held one of its samples."""
     request: int
     pos: int
     x: np.ndarray
@@ -227,27 +233,45 @@ class Served(NamedTuple):
     future: object
 
 
-class Client(threading.Thread):
-    """Submits the kind's requests as the mix says; records per sample its
-    request, due, submit and done times (seconds from the window's open),
-    the micro-batch it was coded in, and its future.
+def _own_samples(cfg: dict, request: np.ndarray) -> np.ndarray:
+    """A request that is its own samples: an (n, M) array."""
+    return request
 
-    Each sample's callback is attached while the probe's gate is held, so
-    no solve that holds the sample starts before its callback is in place:
-    the callback runs in the batcher as the future resolves, and reads the
-    done time and the solve it came from then.  A kind without `submit` is
+
+class Client(threading.Thread):
+    """Submits the kind's requests as the mix says, and records per future
+    it gets back its request, the first of that request's samples it
+    answers and how many, its due, submit and done times (seconds from the
+    window's open), and the micro-batch that resolved it.  A request that
+    is answered sample by sample has one record per sample; one that the
+    kind's `submit` answers with one future has one record for all its
+    samples, which `per_sample` repeats for each of them at the close.
+
+    A request's samples, the rows the engine codes (the kind's `samples`,
+    by default the request itself), are taken where the request is drawn,
+    ahead of its due time; past its submission the client keeps the
+    request alone.  The backlog's cap counts samples.
+
+    Each callback is attached while the probe's gate is held, so no solve
+    that holds a sample starts before its callback is in place: the
+    callback runs in the batcher as the future resolves, and reads the done
+    time and the solve it came from then.  A kind without `submit` is
     submitted sample by sample, the gate held for one sample at a time, and
     a solve that waits goes before the next sample; a kind's own `submit`
-    holds it for the whole request."""
+    holds it for the whole request, and so the client first waits, outside
+    the gate, until the service's queue has room for the whole request."""
 
     def __init__(self, svc, probe, kind, requests, mix, cfg, seed, seconds):
         super().__init__(name="bench-client", daemon=True)
         self.svc, self.probe, self.kind, self.requests = svc, probe, kind, requests
+        self.samples = functools.partial(getattr(kind, "samples", _own_samples), cfg)
         self.stop = threading.Event()
         self.lock = threading.Lock()
-        self.x, self.req, self.due, self.sub, self.done, self.batch, self.futs = (
-            [], [], [], [], [], [], [])
-        self.first = []  # per request, the index of its first sample
+        self.sent = []  # per request, the request as drawn
+        # per record: its request, first sample, sample count, whether it
+        # answers its whole request, times, micro-batch and future
+        self.req, self.pos, self.n, self.whole = [], [], [], []
+        self.due, self.sub, self.done, self.batch, self.futs = [], [], [], [], []
         self.n_submitted = 0  # requests whose every future is attached
         self.t_open = None
         if mix["arrivals"] == "poisson":
@@ -258,18 +282,23 @@ class Client(threading.Thread):
             self.schedule = None
             self.cap_samples = prefill = int(mix["outstanding_batches"]) * cfg["micro_batch"]
             self.cap = threading.Semaphore(self.cap_samples)
-        self.ready = []  # requests drawn ahead of the window: `prefill` samples or more
-        while sum(len(r) for r in self.ready) < prefill:
-            self.ready.append(next(requests))
+        # (request, samples) drawn ahead of the window: `prefill` samples or more
+        self.ready = []
+        while sum(len(rows) for _, rows in self.ready) < prefill:
+            self.ready.append(self._draw())
 
-    def _next_request(self) -> np.ndarray:
-        request = self.ready.pop(0) if self.ready else next(self.requests)
-        if self.cap is not None and len(request) > self.cap_samples:
-            raise ValueError(f"a request of {len(request)} samples exceeds the backlog's "
+    def _draw(self):
+        request = next(self.requests)
+        return request, self.samples(request)
+
+    def _next_request(self):
+        request, rows = self.ready.pop(0) if self.ready else self._draw()
+        if self.cap is not None and len(rows) > self.cap_samples:
+            raise ValueError(f"a request of {len(rows)} samples exceeds the backlog's "
                              f"cap of {self.cap_samples}")
-        return request
+        return request, rows
 
-    def _on_done(self, i):
+    def _on_done(self, i, n):
         def cb(fut):
             t = time.perf_counter() - self.t_open
             failed = fut.cancelled() or fut.exception() is not None
@@ -278,38 +307,58 @@ class Client(threading.Thread):
                 self.done[i] = t
                 self.batch[i] = bid
             if self.cap is not None:
-                self.cap.release()
+                self.cap.release(n)
         return cb
 
-    def _attach(self, i, fut, t_sub):
-        self.sub[i] = t_sub
-        self.futs[i] = fut
-        fut.add_done_callback(self._on_done(i))
-
-    def _submit(self, request, due):
-        n = len(request)
+    def _attach(self, r, pos, n, whole, due, fut, t_sub):
         with self.lock:
-            i0 = len(self.x)
-            self.first.append(i0)
-            self.x.extend(request)
-            self.req.extend([len(self.first) - 1] * n)
-            for column, value in ((self.due, due), (self.sub, None), (self.done, None),
-                                  (self.batch, None), (self.futs, None)):
-                column.extend([value] * n)
+            i = len(self.futs)
+            for column, value in ((self.req, r), (self.pos, pos), (self.n, n),
+                                  (self.whole, whole), (self.due, due), (self.sub, t_sub),
+                                  (self.done, None), (self.batch, None), (self.futs, fut)):
+                column.append(value)
+        fut.add_done_callback(self._on_done(i, n))
+
+    def _room(self, n) -> bool:
+        """Wait, outside the probe's gate, until the service's queue has room
+        for n more samples; False if the client is stopped meanwhile.  A
+        request that filled the queue while its submission held the gate
+        would keep the batcher, which waits at the gate with the batch it
+        took, from ever making more room."""
+        cap = self.svc.cfg.queue_capacity
+        if cap <= 0:
+            return True
+        if n > cap:
+            raise ValueError(f"a request of {n} samples exceeds the service's queue of {cap}")
+        while self.svc.load()["queue_depth"] > cap - n:
+            if self.stop.wait(0.001):
+                return False
+        return True
+
+    def _submit(self, request, rows, due):
+        with self.lock:
+            r = len(self.sent)
+            self.sent.append(request)
         submit = getattr(self.kind, "submit", None)
         if submit is None:
-            for j, x in enumerate(request):
+            for j, x in enumerate(rows):
                 with self.probe.gate.held():
                     fut = self.svc.submit(x)
-                    self._attach(i0 + j, fut, time.perf_counter() - self.t_open)
+                    self._attach(r, j, 1, False, due, fut, time.perf_counter() - self.t_open)
         else:
+            if not self._room(len(rows)):
+                return
             with self.probe.gate.held():
-                futs = submit(self.svc, request)
+                got = submit(self.svc, request)
                 t_sub = time.perf_counter() - self.t_open
-                if len(futs) != n:
-                    raise RuntimeError(f"the kind gave {len(futs)} futures for {n} samples")
-                for j, fut in enumerate(futs):
-                    self._attach(i0 + j, fut, t_sub)
+                if isinstance(got, Future):
+                    self._attach(r, 0, len(rows), True, due, got, t_sub)
+                elif len(got) != len(rows):
+                    raise RuntimeError(f"the kind gave {len(got)} futures for {len(rows)} "
+                                       f"samples")
+                else:
+                    for j, fut in enumerate(got):
+                        self._attach(r, j, 1, False, due, fut, t_sub)
         with self.lock:
             self.n_submitted += 1
 
@@ -317,24 +366,37 @@ class Client(threading.Thread):
         try:
             if self.schedule is not None:
                 for due in self.schedule:
-                    request = self._next_request()
+                    request, rows = self._next_request()
                     wait = self.t_open + due - time.perf_counter()
                     if wait > 0 and self.stop.wait(wait):
                         return
                     if self.stop.is_set():
                         return
-                    self._submit(request, float(due))
+                    self._submit(request, rows, float(due))
             else:
                 while not self.stop.is_set():
-                    request = self._next_request()
-                    for _ in range(len(request)):
+                    request, rows = self._next_request()
+                    for _ in range(len(rows)):
                         while not self.cap.acquire(timeout=0.05):
                             if self.stop.is_set():
                                 return
-                    self._submit(request, 0.0)
+                    self._submit(request, rows, 0.0)
         except RuntimeError:
             if not self.stop.is_set():  # the service stopped under the client
                 raise
+
+    def per_sample(self):
+        """(request, due, submitted, done, batch), each a list with one entry
+        per sample submitted: a record's values repeated for every sample it
+        answers, in the order of the requests' samples."""
+        with self.lock:
+            cols = [list(c) for c in (self.req, self.due, self.sub, self.done, self.batch)]
+            n = list(self.n)
+        return tuple([v for v, k in zip(c, n) for _ in range(k)] for c in cols)
+
+    def rows(self, r: int) -> np.ndarray:
+        """The samples of request r, taken again from the request."""
+        return self.samples(self.sent[r])
 
 
 def peak_bytes(devices) -> int:
@@ -349,8 +411,9 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
     """One run; returns the result object (without printing it).  `wrap`
     replaces the engine under the service (tests plant faults with it);
     `ctx_out`, when given, receives what the metric readers read, and
-    `samples` (per sample its row, solve index and done time) and `solves`
-    (per solve its times and input rows, read to the host)."""
+    `samples` (per sample its row, request, due time, solve index and done
+    time) and `solves` (per solve its times and input rows, read to the
+    host); only then does the probe keep each solve's input."""
     from repro.runtime.service import DictionaryService
 
     kind = kind_of(cfg, bench)
@@ -361,7 +424,8 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
     W0_fn = kind.w0(cfg, mesh, seed)
     rng = np.random.default_rng(traffic.seed_words(seed, 5))
     cols, digest = digest_atoms(cfg, seed)
-    probe = EngineProbe(coder, digest, FOLLOW_FITS if learn else 0)
+    probe = EngineProbe(coder, digest, FOLLOW_FITS if learn else 0,
+                        keep_solve_inputs=ctx_out is not None)
     W0 = W0_fn()
     if learn:
         jax.block_until_ready(digest(W0))  # compiled here, not in the window
@@ -419,51 +483,53 @@ def run_cell(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float, traced
 
     # -- what the window did -------------------------------------------------
     memory_peak = peak_bytes(devices)
-    with client.lock:
-        due, sub, done, bid = list(client.due), list(client.sub), list(client.done), list(client.batch)
+    req, due, sub, done, bid = client.per_sample()
     solves = [dict(s, t0=s["t0"] - t_open, t1=s["t1"] - t_open) for s in probe.solves]
     fits = [dict(f, t0=f["t0"] - t_open, t1=f["t1"] - t_open, x=np.asarray(f["x"]))
             for f in probe.fits]
     for f in fits:
         f["rows"] = len(reference.real_rows(f["x"]))
+    # a sample that failed took no micro-batch
     per_batch = {}
     for i, b in enumerate(bid):
-        if b is not None and done[i] is not None and client.futs[i].exception() is None:
+        if b is not None:
             t, n = per_batch.get(b, (0.0, 0))
             per_batch[b] = (max(t, done[i]), n + 1)
     # Samples offered in the window; a backlog's samples still queued at
     # the close were offered past it and are not counted.
-    in_window = [i for i in range(len(due)) if due[i] < seconds and sub[i] is not None
-                 and sub[i] <= t_close]
+    in_window = [i for i in range(len(due)) if due[i] < seconds and sub[i] <= t_close]
     if mix["arrivals"] == "backlog":
         in_window = [i for i in in_window if done[i] is not None and done[i] <= t_close]
-    failed = sum(1 for i in in_window
-                 if done[i] is None or client.futs[i].exception() is not None)
+    failed = sum(1 for i in in_window if bid[i] is None)
     ctx = dict(
         seconds=seconds, cfg=cfg, mix=mix, cell=cell, chips=len(devices),
         kind=devices[0].device_kind, setup_s=setup_s, t_close=t_close, stats=stats,
         batches=sorted(per_batch.values()), fits=[(f["t1"], f["rows"]) for f in fits],
         due=[due[i] for i in in_window],
         submitted=[sub[i] for i in in_window],
-        done=[done[i] if client.futs[i].exception() is None else None for i in in_window],
+        done=[done[i] if bid[i] is not None else None for i in in_window],
     )
+    rows = functools.lru_cache(maxsize=1)(client.rows)  # a request's records are adjacent
     if ctx_out is not None:
-        ctx_out.update(ctx, samples=dict(x=list(client.x), batch=bid, done=done),
+        x = [row for r, p, n in zip(client.req, client.pos, client.n) for row in rows(r)[p:p + n]]
+        ctx_out.update(ctx, samples=dict(x=x, request=req, due=due, batch=bid, done=done),
                        solves=[dict(s, x=np.asarray(s["x"])) for s in solves])
 
     # -- the check -------------------------------------------------------------
     n_follow = min(FOLLOW_FITS, len(fits)) if learn else 0
-    version = [solves[b]["version"] if b is not None and b < len(solves) else -1 for b in bid]
-    served = [Served(client.req[i], i - client.first[client.req[i]], client.x[i], version[i],
-                     client.futs[i])
-              for i in range(len(bid)) if done[i] is not None and version[i] >= 0
-              and version[i] <= n_follow and client.futs[i].exception() is None]
+    served = []
+    for i, (r, b, d, fut) in enumerate(zip(client.req, client.batch, client.done, client.futs)):
+        version = solves[b]["version"] if b is not None and b < len(solves) else -1
+        if d is not None and 0 <= version <= n_follow:
+            x = client.sent[r] if client.whole[i] else rows(r)[client.pos[i]]
+            served.append(Served(r, client.pos[i], x, version, fut))
     picked, unchecked = kind.pick(cfg, served, rng)
     fit_inputs = [f["x"] for f in fits[:n_follow]]
-    foreign = reference.foreign_rows(fit_inputs, {hash(x.tobytes()) for x in client.x})
+    foreign = reference.foreign_rows(
+        fit_inputs, {hash(x.tobytes()) for r in range(len(client.sent)) for x in rows(r)})
     digests = [None] + probe.digests[:n_follow]
     fit_failures = stats["fit_failures"]
-    del svc, probe, coder, client, solves, fits, served
+    del svc, probe, coder, client, solves, fits, served, rows
     gc.collect()
     numbers = kind.check(cfg, seed, W0_fn, picked, fit_inputs, cols, digests)
     checks = reference.judge(numbers, cfg["limits"])

@@ -1,18 +1,23 @@
 """A toy image-denoising kind, which the harness's tests add as
 `kinds/tiles.py` to show that a deployment plugs in by new files alone.
 
-Images of `image` x `image` pixels, each cut into all its overlapping
-`patch` x `patch` patches (column-major, as the paper stacks them), with
-each patch's mean (its DC) removed; one image's patches are one request,
-due together.  W0 is a fixed overcomplete 2-D DCT dictionary (the seed does
-not enter).  The reference denoises each patch by the exact_fista dual as
-x - nu, puts its DC back, and rebuilds the tile by averaging the patches
-over each pixel; `tile_gap` is the worst tile's ||served - ref|| / ||ref||.
+A request is one noisy image of `image` x `image` pixels.  Its samples are
+all its overlapping `patch` x `patch` patches (column-major, as the paper
+stacks them), each with its mean (its DC) removed.  The service takes no
+image yet, so `submit` cuts the patches, submits them one by one, and
+answers the request with one future that resolves to the patches' nu once
+the last of them is back.  W0 is a fixed overcomplete 2-D DCT dictionary
+(the seed does not enter).  The reference draws the image again from the
+seed, denoises each patch by the exact_fista dual as x - nu, puts its DC
+back, and rebuilds the tile by averaging the patches over each pixel;
+`tile_gap` is the worst tile's ||served - ref|| / ||ref||.
 """
 
 from __future__ import annotations
 
 import math
+import threading
+from concurrent.futures import Future
 from typing import Callable, Dict, Iterator
 
 import jax
@@ -45,6 +50,11 @@ def _patches(img: np.ndarray, p: int) -> np.ndarray:
     return win.transpose(0, 1, 3, 2).reshape(-1, p * p)
 
 
+def _dc_removed(img: np.ndarray, p: int) -> np.ndarray:
+    x = _patches(img, p)
+    return (x - x.mean(axis=1, keepdims=True)).astype(np.float32)
+
+
 def _overlap_average(patches: np.ndarray, side: int, p: int) -> np.ndarray:
     """The image whose every pixel is the mean of the patches covering it."""
     acc = np.zeros((side, side))
@@ -55,6 +65,26 @@ def _overlap_average(patches: np.ndarray, side: int, p: int) -> np.ndarray:
         acc[i:i + p, j:j + p] += patch.reshape(p, p).T
         cnt[i:i + p, j:j + p] += 1.0
     return acc / cnt
+
+
+def _gather(futs: list) -> Future:
+    """One future for `futs`: the stack of their nu, in their order, once
+    the last is back, or the first failure among them."""
+    out, left, lock = Future(), [len(futs)], threading.Lock()
+
+    def one(_):
+        with lock:
+            left[0] -= 1
+            if left[0]:
+                return
+        try:
+            out.set_result(np.stack([np.asarray(f.result()[0]) for f in futs]))
+        except Exception as err:  # a patch failed: so does the tile
+            out.set_exception(err)
+
+    for f in futs:
+        f.add_done_callback(one)
+    return out
 
 
 def w0(cfg: dict, mesh, seed: int) -> Callable:
@@ -77,33 +107,33 @@ def w0(cfg: dict, mesh, seed: int) -> Callable:
 
 
 def requests(cfg: dict, mix: dict, seed: int) -> Iterator[np.ndarray]:
-    """Image r's DC-removed patches, r = 0, 1, 2, ..."""
+    """Image r, r = 0, 1, 2, ..."""
     if mix["learn"]:
         raise ValueError("tiles: the toy kind does not learn")
     r = 0
     while True:
-        x = _patches(_image(cfg, seed, r), cfg["patch"])
-        yield (x - x.mean(axis=1, keepdims=True)).astype(np.float32)
+        yield _image(cfg, seed, r)
         r += 1
 
 
+def samples(cfg: dict, request: np.ndarray) -> np.ndarray:
+    """The image's DC-removed patches."""
+    return _dc_removed(request, cfg["patch"])
+
+
+def submit(svc, request: np.ndarray) -> Future:
+    """The image's DC-removed patches, each submitted as a sample; one
+    future for them all."""
+    return _gather(svc.submit_many(_dc_removed(request, math.isqrt(svc.sample_dim))))
+
+
 def pick(cfg: dict, served: list, rng: np.random.Generator):
-    """CHECK_TILES tiles drawn from the seed among those whose every patch
-    was served: [{"request", "x", "nu"}], patches in the request's order."""
-    n = (cfg["image"] - cfg["patch"] + 1) ** 2
-    tiles: Dict[int, dict] = {}
-    for s in served:
-        tiles.setdefault(s.request, {})[s.pos] = s
-    whole = sorted(r for r, got in tiles.items() if len(got) == n)
-    chosen = sorted(rng.choice(len(whole), min(CHECK_TILES, len(whole)), replace=False)
-                    ) if whole else []
-    picked = []
-    for j in chosen:
-        got = tiles[whole[j]]
-        picked.append({"request": whole[j], "x": np.stack([got[k].x for k in range(n)]),
-                       "nu": np.stack([np.asarray(got[k].future.result()[0])
-                                       for k in range(n)])})
-    return picked, (CHECK_TILES - len(chosen)) * n
+    """CHECK_TILES served tiles drawn from the seed: [{"request", "nu"}],
+    nu of the patches in their order."""
+    chosen = sorted(rng.choice(len(served), min(CHECK_TILES, len(served)), replace=False)
+                    ) if served else []
+    picked = [{"request": served[j].request, "nu": served[j].future.result()} for j in chosen]
+    return picked, (CHECK_TILES - len(chosen)) * (cfg["image"] - cfg["patch"] + 1) ** 2
 
 
 def check(cfg: dict, seed: int, W0_fn, picked: list, fits: list, cols, digests
@@ -113,9 +143,10 @@ def check(cfg: dict, seed: int, W0_fn, picked: list, fits: list, cols, digests
     side, p = cfg["image"], cfg["patch"]
     gap = 0.0
     for tile in picked:
-        nu_ref, _ = reference.solve(W, jnp.asarray(tile["x"]), **args)
-        dc = _patches(_image(cfg, seed, tile["request"]), p).mean(axis=1, keepdims=True)
-        served = _overlap_average(tile["x"] - tile["nu"] + dc, side, p)
-        ref = _overlap_average(tile["x"] - np.asarray(nu_ref) + dc, side, p)
+        img = _image(cfg, seed, tile["request"])
+        x, dc = _dc_removed(img, p), _patches(img, p).mean(axis=1, keepdims=True)
+        nu_ref, _ = reference.solve(W, jnp.asarray(x), **args)
+        served = _overlap_average(x - tile["nu"] + dc, side, p)
+        ref = _overlap_average(x - np.asarray(nu_ref) + dc, side, p)
         gap = max(gap, float(np.linalg.norm(served - ref) / np.linalg.norm(ref)))
     return {"tile_gap": gap}

@@ -80,47 +80,95 @@ def _files(root: pathlib.Path) -> dict:
             if p.is_file() and "__pycache__" not in p.parts}
 
 
+# Appended to the tiles kind: its `submit` answers with the patches' futures
+# gathered in reverse, so each tile is rebuilt from another patch's answer.
+REVERSED = """
+
+def submit(svc, request):
+    patches = _dc_removed(request, math.isqrt(svc.sample_dim))
+    return _gather(svc.submit_many(patches)[::-1])
+"""
+# Appended last: counts the done callbacks attached to each request's future.
+COUNTED = """
+
+CALLBACKS = []  # per request submitted, the callbacks attached to its future
+_uncounted_submit = submit
+
+
+def submit(svc, request):
+    fut = _uncounted_submit(svc, request)
+    attached = []
+    CALLBACKS.append(attached)
+    add = fut.add_done_callback
+
+    def counted(fn):
+        attached.append(fn)
+        add(fn)
+
+    fut.add_done_callback = counted
+    return fut
+"""
+PATCHES = (64 - 10 + 1) ** 2  # 3,025 overlapping 10x10 patches of a 64x64 image
+
+
 @pytest.mark.parametrize("fault,arrivals", [(None, "poisson"), ("patches_reversed", "poisson"),
                                             (None, "backlog")])
 def test_a_new_kind_runs_by_new_files_alone(tmp_path, fault, arrivals):
-    """A toy denoising deployment: 16x16 images, 4x4 patches (M=16), each
-    image's 169 patches one request due at once, its own W0 and reference.
-    With its answers handed to the wrong patches it must not be correct.
-    Under a backlog the cap counts samples, a request takes 169 of them."""
+    """A toy denoising deployment at the paper's shapes (Sec. V): 64x64
+    images, 10x10 patches (M=100) against a K=196 DCT W0, its own
+    reference.  A request is the image; its 3,025 DC-removed patches are
+    its samples, and the kind answers it with one future, so the client
+    attaches one callback per request, and every sample of a request
+    carries the request's due and done times.  The solve runs 10
+    iterations, not the paper's count: the reference runs as many, so the
+    comparison holds at any count, and the CPU run stays short.  With its
+    answers handed to the wrong patches it must not be correct.  Under a
+    backlog the cap counts samples: 24 micro-batches of 256 hold two
+    requests, and the service's queue room for one and a part, so the
+    client has to wait for room before it takes the probe's gate (holding
+    it on a full queue, it would keep the batcher from making room)."""
     bench = tmp_path / "bench"
     shutil.copytree(BENCH, bench, ignore=shutil.ignore_patterns("__pycache__", "tests"))
     before = _files(bench)
 
-    source = TILES_KIND.read_text()
-    if fault == "patches_reversed":
-        source += ("\n\ndef submit(svc, request):\n"
-                   "    return svc.submit_many(request)[::-1]\n")
-    (bench / "kinds" / "tiles.py").write_text(source)
-    cfg = {"name": "tiles-m16-k36", "kind": "tiles", "image": 16, "patch": 4, "noise": 0.1,
-           "m": 16, "atoms": 36, "mesh": [1, 1], "task": "sparse_svd", "gamma": 0.05,
-           "delta": 0.2, "mode": "exact_fista", "iters": 60, "micro_batch": 64,
-           "max_wait_ms": 20, "mu_w": 0.1, "dtype": "float32", "limits": {"tile_gap": 1e-4}}
-    (bench / "configs" / "tiles-m16-k36.json").write_text(json.dumps(cfg))
+    source = TILES_KIND.read_text() + (REVERSED if fault == "patches_reversed" else "")
+    (bench / "kinds" / "tiles.py").write_text(source + COUNTED)
+    cfg = {"name": "tiles-m100-k196", "kind": "tiles", "image": 64, "patch": 10,
+           "noise": 0.1, "m": 100, "atoms": 196, "mesh": [1, 1], "task": "sparse_svd",
+           "gamma": 0.05, "delta": 0.2, "mode": "exact_fista", "iters": 10,
+           "micro_batch": 256, "max_wait_ms": 20, "mu_w": 0.1, "queue_capacity": 4096,
+           "dtype": "float32", "limits": {"tile_gap": 1e-4}}
+    (bench / "configs" / "tiles-m100-k196.json").write_text(json.dumps(cfg))
     (bench / "traffic" / "tiles.rate.json").write_text(json.dumps(
-        {"arrivals": arrivals, "rate_per_s": 4.0, "outstanding_batches": 4, "learn": False}))
+        {"arrivals": arrivals, "rate_per_s": 3.0, "outstanding_batches": 24, "learn": False}))
     man = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    man["configs"].append({"name": "tiles-m16-k36", "source": "https://arxiv.org/abs/1402.1515",
-                           "file": "bench/configs/tiles-m16-k36.json", "reduced": [],
+    man["configs"].append({"name": "tiles-m100-k196", "source": "https://arxiv.org/abs/1402.1515",
+                           "file": "bench/configs/tiles-m100-k196.json", "reduced": [],
                            "why": "test"})
-    man["workloads"].append({"name": "tiles.rate", "config": "tiles-m16-k36",
+    man["workloads"].append({"name": "tiles.rate", "config": "tiles-m100-k196",
                              "traffic": "tiles.rate", "chips": 1, "why": "test"})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(man))
 
     man = run.manifest(tmp_path)
     cell, found, mix = run.cell_parts("tiles.rate", man, bench)
     entries = [{"name": n, "unit": "ms"} for n in ("latency_p95_ms", "client.late_p95_ms")]
-    out = tiny_run("tiles.rate", cfg=found, bench=bench, cell=cell, entries=entries,
-                   mix_over={"rate_per_s": mix["rate_per_s"]})
+    seed, seconds, ctx = 2**31 + 99, 2.0, {}
+    out = run.run_cell(cell, found, mix, seed, seconds, False, entries, jax.devices()[:1],
+                       t_process=time.perf_counter(), bench=bench, ctx_out=ctx)
 
     assert _files(bench).items() >= before.items()  # no file that was there is edited
-    assert out["attempted"] >= 2 * 169
-    if arrivals == "poisson":  # every request due in the window is counted whole
-        assert out["attempted"] % 169 == 0
+    callbacks = [len(c) for c in run.kind_of(found, bench).CALLBACKS]
+    samples = ctx["samples"]
+    times = {}
+    for r, due, done in zip(samples["request"], samples["due"], samples["done"]):
+        times.setdefault(r, []).append((due, done))
+    assert callbacks == [1] * len(times)  # one callback per request, not per sample
+    assert all(len(t) == PATCHES and len(set(t)) == 1 for t in times.values())
+    if arrivals == "poisson":  # every request due in the window, each counted whole
+        offered = len(traffic.due_times(mix, seed, seconds))
+    else:  # the requests answered by the window's close
+        offered = sum(t[0][1] is not None and t[0][1] <= ctx["t_close"] for t in times.values())
+    assert offered >= 2 and out["attempted"] == PATCHES * offered
     assert out["checks"]["samples_unchecked"]["value"] == 0
     assert out["metrics"]["latency_p95_ms"]["value"] > 0
     if fault is None:
@@ -185,6 +233,32 @@ def test_each_sample_of_a_request_is_timed_by_the_solve_that_held_it(tmp_path, s
         assert solves[b]["t1"] <= done, (i, b)
         if b + 1 < len(solves):
             assert done <= solves[b + 1]["t0"], (i, b)
+
+
+@pytest.mark.parametrize("asked", [False, True])
+def test_the_probe_keeps_a_solve_input_only_when_asked(monkeypatch, asked):
+    """A run keeps no solve's input to the window's close, where it would
+    count in the peak memory, unless the caller asks for the solves
+    (`ctx_out`); every fit keeps its input, which the reference follows
+    and `learned_per_s` counts the rows of."""
+    from probe import EngineProbe
+
+    probes = []
+
+    class Kept(EngineProbe):
+        def __init__(self, *args, **kw):
+            super().__init__(*args, **kw)
+            probes.append(self)
+
+    monkeypatch.setattr(run, "EngineProbe", Kept)
+    cfg, mix = tiny_config(), traffic.load("learn.backlog")
+    out = run.run_cell({"name": "tiny", "config": cfg["name"], "traffic": "learn.backlog",
+                        "chips": 1}, cfg, mix, 2**31 + 11, 2.0, False, [], jax.devices()[:1],
+                       t_process=time.perf_counter(), ctx_out={} if asked else None)
+    assert out["correct"], out["checks"]
+    (probe,) = probes
+    assert probe.solves and all(("x" in s) == asked for s in probe.solves)
+    assert probe.fits and all("x" in f for f in probe.fits)
 
 
 def test_a_waiting_solve_goes_before_the_clients_next_sample():
